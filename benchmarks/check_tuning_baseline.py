@@ -61,6 +61,7 @@ _CAL_ROW_KEYS = ("label", "h2d_bytes", "d2h_bytes", "loads", "stores",
 
 def _gate_programs() -> Dict[str, object]:
     import directive_micro as dm
+    import jax
     from repro.optim.offload import attention_step_program
     from repro.polybench import build_3mm
     saved = dm.N, dm.ITERS
@@ -70,7 +71,8 @@ def _gate_programs() -> Dict[str, object]:
             "fig4_advancedload": dm._advancedload_prog(),
             "fig5_delegatestore": dm._delegatestore_prog(),
             "table2_3mm": build_3mm(n=QUICK_N)[0],
-            "attn_step": attention_step_program(n_steps=1),
+            "attn_step": attention_step_program(
+                n_steps=1, interpret=jax.default_backend() == "cpu"),
         }
     finally:
         dm.N, dm.ITERS = saved
@@ -262,6 +264,8 @@ def _check_report(report_path: str, golden: Dict, tol: float) -> List[str]:
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     args = list(sys.argv[1:] if argv is None else argv)
     if "--update" in args:
         update()

@@ -40,9 +40,11 @@ import sys
 import time
 from typing import Dict
 
+import jax
 import numpy as np
 
 from repro.core import Program, execute, naive_plan, plan
+from repro.launch.compile_cache import use_compile_cache
 
 N = 1536
 ITERS = 8
@@ -184,7 +186,8 @@ def bench_tuner(out_path: str = "tuning_report.json") -> Dict:
         "fig4_advancedload": _advancedload_prog(),
         "fig5_delegatestore": _delegatestore_prog(),
         "table2_3mm": p3,
-        "attn_step": attention_step_program(n_steps=1),
+        "attn_step": attention_step_program(
+            n_steps=1, interpret=jax.default_backend() == "cpu"),
     }
     # the kernel program's interesting axis is the tile grid; pin the
     # plan axes so the smoke run measures kernel VARIANTS (interpret-mode
@@ -266,6 +269,7 @@ def write_bench_snapshot(rows: Dict, path: str = None) -> str:
 
 def main(argv=None):
     global N, ITERS, REPS
+    use_compile_cache()
     args = list(sys.argv[1:] if argv is None else argv)
     if "--quick" in args:
         N, ITERS, REPS = 256, 4, 1   # CI smoke: exercise every column fast

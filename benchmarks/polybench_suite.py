@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.core import execute, naive_plan, plan, run_host_oracle
 from repro.core.backend import _jitted_block as _jitted
+from repro.launch.compile_cache import use_compile_cache
 from repro.polybench import PROBLEMS, build
 
 SIZES = {
@@ -106,6 +107,7 @@ def run_suite() -> List[Dict]:
 
 
 def main():
+    use_compile_cache()
     rows = run_suite()
     for r in rows:
         print(f"fig6_{r['problem']},{r['t_omp2hmpp_ms'] * 1e3:.0f},"

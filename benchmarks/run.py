@@ -13,6 +13,8 @@ Output: ``name,us_per_call,derived`` CSV rows.
 from __future__ import annotations
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
 
     from benchmarks import table2_3mm

@@ -36,6 +36,7 @@ import time
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import Engine, ServeRuntime, make_trace
 
 # mostly-short with a long tail: the traffic shape where static batching
@@ -131,6 +132,7 @@ def bench(*, arch: str, n_requests: int, capacity: int, max_seq: int,
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-14b,rwkv6-3b",
                     help="comma-separated arch list (one bench row each)")

@@ -8,6 +8,7 @@ schedule vs the naive policy.
 from __future__ import annotations
 
 from repro.core import emit, execute, naive_plan, plan, transfer_summary
+from repro.launch.compile_cache import use_compile_cache
 from repro.polybench import build
 
 
@@ -36,6 +37,7 @@ def run(n: int = 512, show_source: bool = True):
 
 
 def main():
+    use_compile_cache()
     row = run(show_source=True)
     extra = ";".join(f"{k}={v if not isinstance(v, float) else round(v,2)}"
                      for k, v in row.items() if k != "wall_opt_ms")

@@ -23,6 +23,7 @@ import jax
 from repro.configs import get_config, reduced
 from repro.core import execute, naive_plan, plan
 from repro.data import PrefetchIterator, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import make_train_step
 from repro.models import Transformer
 from repro.optim import default_optimizer, plan_step_program
@@ -141,6 +142,7 @@ def run_plan_executor(n_steps: int = 64, reps: int = 3):
 
 
 def main():
+    use_compile_cache()
     r = run()
     print(f"{r['name']},{r['t_planned_ms'] * 1e3 / STEPS:.0f},"
           f"speedup={r['speedup']:.2f}x;sync_ms={r['t_sync_ms']:.0f};"

@@ -23,11 +23,9 @@ events — so the same ``Plan`` can run against:
 
 ``PinnedHostBackend``
     Same as ``JaxDeviceBackend`` but the host side of every transfer is
-    staged in ``pinned_host`` device memory when the platform supports it
-    (see ``repro.optim.offload.host_memory_kind``), which is what makes
-    h2d genuinely overlappable on TPU.  Falls back to plain
-    ``JaxDeviceBackend`` behaviour on platforms without a pinned space
-    (e.g. CPU jaxlib builds).
+    staged in the device's ``pinned_host`` memory, which is what makes
+    h2d genuinely overlappable on TPU.  Construction fails on a device
+    without a pinned space (``repro.optim.offload.host_memory_kind``).
 
 Streams are logical ids chosen by the planner (``AdvancedLoad.stream``
 etc.); a backend may map many logical streams onto fewer physical ones
@@ -318,10 +316,6 @@ class JaxDeviceBackend(Backend):
         import jax.numpy as jnp
         return jnp
 
-    # host-side staging sharding for transfers; None = plain device_put
-    def _host_space(self):
-        return None
-
     def alloc(self, shape, dtype):
         import jax.numpy as jnp
         return jnp.zeros(shape, dtype)
@@ -332,9 +326,6 @@ class JaxDeviceBackend(Backend):
         return handle
 
     def download(self, handle, *, stream: int = 0):
-        staged = self._host_space()
-        if staged is not None:
-            handle = self._jax.device_put(handle, staged)
         return np.asarray(handle)                           # wait point
 
     def free(self, handle) -> None:
@@ -417,9 +408,10 @@ class JaxDeviceBackend(Backend):
 
 class PinnedHostBackend(JaxDeviceBackend):
     """JAX backend whose transfers stage through ``pinned_host`` memory —
-    the ``optim/offload.py`` machinery applied to the block executor.  On
-    platforms with no pinned space this degrades to ``JaxDeviceBackend``
-    (the logical plan semantics are unchanged either way)."""
+    the ``optim/offload.py`` machinery applied to the block executor.
+    Both hops name their memory kind explicitly: a bare-device
+    ``device_put`` of a pinned buffer is read by JAX as a same-device
+    copy and refused for its memory kind."""
 
     name = "pinned"
 
@@ -428,19 +420,22 @@ class PinnedHostBackend(JaxDeviceBackend):
         super().__init__(device, n_streams=n_streams, donate=donate)
         from repro.optim.offload import host_memory_kind
         kind = host_memory_kind(self._device)
-        self._pinned_sharding = None
-        if kind is not None:
-            self._pinned_sharding = (
-                self._jax.sharding.SingleDeviceSharding(self._device)
-                .with_memory_kind(kind))
-
-    def _host_space(self):
-        return self._pinned_sharding
+        if kind is None:
+            raise RuntimeError(
+                f"{self._device} has no pinned_host memory: the pinned "
+                f"backend cannot stage transfers on it")
+        sds = self._jax.sharding.SingleDeviceSharding
+        self._pinned = sds(self._device, memory_kind=kind)
+        self._on_device = sds(self._device, memory_kind="device")
 
     def upload(self, host, *, stream: int = 0, name=None):
-        if self._pinned_sharding is not None:
-            host = self._jax.device_put(host, self._pinned_sharding)
-        return super().upload(host, stream=stream, name=name)
+        staged = self._jax.device_put(host, self._pinned)
+        handle = self._jax.device_put(staged, self._on_device)
+        self._record(stream, Event(payload=handle))
+        return handle
+
+    def download(self, handle, *, stream: int = 0):
+        return np.asarray(self._jax.device_put(handle, self._pinned))
 
 
 _REGISTRY: Dict[str, Callable[[], Backend]] = {

@@ -88,9 +88,10 @@ import dataclasses
 import itertools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from ..roofline.analysis import (HW, candidate_features, dot_flops,
+from ..roofline.analysis import (PEAKS, candidate_features, dot_flops,
                                  fit_candidate_predictor,
-                                 fit_offload_constants, kernel_roofline_terms,
+                                 fit_offload_constants, hw_for,
+                                 kernel_roofline_terms,
                                  offload_cost_terms, parse_hlo,
                                  predict_candidate_s, rank_correlation)
 from .analysis import ProgramAnalysis, analyze
@@ -217,15 +218,11 @@ def _block_flops(program: Program,
     double-count kernel_s across blocks).  Kernel-tagged blocks are
     skipped (0.0): they are priced analytically per tile variant via
     ``kernel_roofline_terms``, and lowering a Pallas call in interpret
-    mode is both slow and uncountable here.  Falls back to 0 for bodies
-    that fail to lower (the cost model then ranks on transfer + dispatch
-    terms alone, which are the plan-dependent ones anyway)."""
+    mode is both slow and uncountable here.  A body the device's compiler
+    refuses fails the tune: its error propagates."""
+    import jax
+    import jax.numpy as jnp
     out: Dict[int, float] = {}
-    try:
-        import jax
-        import jax.numpy as jnp
-    except Exception:            # pragma: no cover - jax is baked in
-        return {b.idx: 0.0 for b in program.offload_blocks()}
     for blk in program.offload_blocks():
         if blk.kernel:
             out[blk.idx] = 0.0
@@ -236,11 +233,8 @@ def _block_flops(program: Program,
             o = _blk.fn(jnp, **dict(zip(_blk.reads, arrays)))
             return tuple(o[w] for w in _blk.writes)
 
-        try:
-            txt = jax.jit(wrapped).lower(*avals).compile().as_text()
-            out[blk.idx] = dot_flops(parse_hlo(txt))
-        except Exception:
-            out[blk.idx] = 0.0
+        txt = jax.jit(wrapped).lower(*avals).compile().as_text()
+        out[blk.idx] = dot_flops(parse_hlo(txt))
     return out
 
 
@@ -248,21 +242,21 @@ def _kernel_block_terms(blk, params, shapes,
                         hw) -> Optional[Dict[str, float]]:
     """Analytic (flops, kernel_bytes) for a kernel-tagged block priced at
     tile choice ``params`` (None → the registry defaults) on the block's
-    declared-read operand shapes.  None when the registry cannot price it
-    (unknown kernel, missing shapes, invalid tile) — the caller then
-    falls back to the generic HLO/nbytes pricing."""
+    declared-read operand shapes.  None when the registry has no such
+    kernel or a read has no shape — the caller then falls back to the
+    generic HLO/nbytes pricing.  An invalid tile raises ``ValueError``."""
     import numpy as np
-    try:
-        sds = [shapes[v] for v in blk.reads]
-        op_shapes = [tuple(s.shape) for s in sds]
-        itemsizes = [int(np.dtype(s.dtype).itemsize) for s in sds]
-        if params is None:
-            from repro.kernels.variants import KERNELS
-            params = KERNELS[blk.kernel]["defaults"]
-        return kernel_roofline_terms(blk.kernel, dict(params), op_shapes,
-                                     itemsizes, hw=hw)
-    except Exception:
+
+    from repro.kernels.variants import KERNELS
+    if blk.kernel not in KERNELS or any(v not in shapes for v in blk.reads):
         return None
+    sds = [shapes[v] for v in blk.reads]
+    op_shapes = [tuple(s.shape) for s in sds]
+    itemsizes = [int(np.dtype(s.dtype).itemsize) for s in sds]
+    if params is None:
+        params = KERNELS[blk.kernel]["defaults"]
+    return kernel_roofline_terms(blk.kernel, dict(params), op_shapes,
+                                 itemsizes, hw=hw)
 
 
 def predict_cost(pl: Plan, cfg: PlanConfig,
@@ -534,6 +528,13 @@ def _donation_variant(be: Backend, donate: bool) -> Backend:
     return be.variant(donate=donate)
 
 
+def _device_hw(be: Backend) -> Dict[str, float]:
+    """Pricing constants of the device ``be`` runs on; the numpy backend
+    runs on the host CPU."""
+    device = getattr(be, "_device", None)
+    return PEAKS["cpu"] if device is None else hw_for(device)
+
+
 def _measurable(program: Program) -> bool:
     return all(type(v).__name__ != "ShapeDtypeStruct"
                for v in program.inputs.values())
@@ -710,17 +711,16 @@ def _kernel_variant_combos(program: Program,
             kernels[blk.kernel] = blk
     if not kernels:
         return [()]
-    from repro.kernels.variants import variants_for
+    from repro.kernels.variants import KERNELS, variants_for
     per_kernel = []
     for name in sorted(kernels):
         blk = kernels[name]
-        try:
-            sds = [an.shapes[v] for v in blk.reads]
-            shapes = [tuple(s.shape) for s in sds]
-            itemsizes = [int(np.dtype(s.dtype).itemsize) for s in sds]
-            vs = variants_for(name, shapes, itemsizes)
-        except Exception:
-            vs = ()
+        if name not in KERNELS or any(v not in an.shapes for v in blk.reads):
+            continue
+        sds = [an.shapes[v] for v in blk.reads]
+        shapes = [tuple(s.shape) for s in sds]
+        itemsizes = [int(np.dtype(s.dtype).itemsize) for s in sds]
+        vs = variants_for(name, shapes, itemsizes)
         if vs:
             per_kernel.append([(name, v.params) for v in vs])
     if not per_kernel:
@@ -806,6 +806,7 @@ def tune(program: Program, *, backend: Any = None,
     from .compile import fusable_loops
     an = analysis or analyze(program)
     be = get_backend(backend)
+    base_hw = _device_hw(be)
     # -- mesh placement axis (ISSUE 9): only on placement-capable backends --
     mesh_capable = (hasattr(be, "with_placement")
                     and getattr(be, "mesh_desc", None) is not None)
@@ -862,7 +863,8 @@ def tune(program: Program, *, backend: Any = None,
         protocol = {"measure": True, "top_k": top_k, "reps": int(reps),
                     "calibrate": bool(calibrate),
                     "use_calibration": bool(use_calibration)}
-        fp = tuning_fingerprint(program, be, cfg_list, protocol, HW)
+        fp = tuning_fingerprint(program, be, cfg_list, protocol,
+                                base_hw)
         # the grid/protocol is part of the SLOT (coexisting entries),
         # not just the fingerprint (which would evict-thrash between
         # alternating protocol variants of the same program); the
@@ -883,9 +885,9 @@ def tune(program: Program, *, backend: Any = None,
                     tc.evict(slot)
 
     # -- pricing constants: calibrated when a fit is cached -----------------
-    pricing_hw = dict(HW)
+    pricing_hw = dict(base_hw)
     if use_calibration and tc is not None:
-        fitted = tc.load_calibration(dc_key, HW)
+        fitted = tc.load_calibration(dc_key, base_hw)
         if fitted:
             pricing_hw.update(fitted)
 
@@ -896,13 +898,14 @@ def tune(program: Program, *, backend: Any = None,
     predictor_source = None
     n_train_rows = 0
     if tc is not None:
-        train_rows = tc.load_measured_rows(dc_key, HW, exclude_fp=prog_fp)
+        train_rows = tc.load_measured_rows(dc_key, base_hw,
+                                           exclude_fp=prog_fp)
         n_train_rows = len(train_rows)
         predictor_model = fit_candidate_predictor(train_rows)
         if predictor_model is not None:
             predictor_source = "fit"
         else:
-            predictor_model = tc.load_predictor(dc_key, HW)
+            predictor_model = tc.load_predictor(dc_key, base_hw)
             if predictor_model is not None:
                 predictor_source = "cache"
 
@@ -976,7 +979,7 @@ def tune(program: Program, *, backend: Any = None,
             base["analytic_s"] = offload_cost_terms(
                 base["h2d_bytes"], base["d2h_bytes"], base["dispatches"],
                 base["syncs"], base["flops"], base["kernel_bytes"],
-                base["coll_bytes"])["predicted_s"]
+                base["coll_bytes"], hw=base_hw)["predicted_s"]
             base["peak_bytes"] = plan_peak_device_bytes(
                 pl, donate=eff_donate,
                 kernel_variants=cfg.variants_map() or None,
@@ -1036,7 +1039,7 @@ def tune(program: Program, *, backend: Any = None,
     if calibrate and measured_survivors:
         calibration = _calibrate(measured_survivors, pricing_hw)
         if calibration["accepted"] and calibration["fitted"] and tc:
-            tc.store_calibration(dc_key, HW, calibration["fitted"])
+            tc.store_calibration(dc_key, base_hw, calibration["fitted"])
 
     # accumulate this program's measured rows into the device-class
     # store — the training set future programs' cold starts fit from.
@@ -1046,7 +1049,7 @@ def tune(program: Program, *, backend: Any = None,
     # here.
     if tc is not None and measured_survivors:
         tc.add_measured_rows(
-            dc_key, HW, prog_fp, program.name,
+            dc_key, base_hw, prog_fp, program.name,
             [dict(candidate_features(r), measured_s=r["measured_s"],
                   program=program.name)
              for r in measured_survivors])
@@ -1074,7 +1077,7 @@ def tune(program: Program, *, backend: Any = None,
                              rank_corr_predictor=corr_p,
                              accepted=corr_p >= corr_a)
             if predictor["accepted"] and predictor_source == "fit":
-                tc.store_predictor(dc_key, HW, predictor_model)
+                tc.store_predictor(dc_key, base_hw, predictor_model)
 
     # merged configs inherit their survivor's measurements
     by_label = {r["label"]: r for r in valid}

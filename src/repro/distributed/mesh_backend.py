@@ -63,10 +63,13 @@ DEFAULT_PLACEMENTS = ("replicate", "fsdp", "tp")
 def auto_mesh_shape(n_devices: int,
                     axes: Tuple[str, str] = ("data", "model")
                     ) -> Tuple[int, int]:
-    """(data, model) shape for ``n_devices``: model = largest of (4, 2, 1)
-    dividing it, data = the rest.  8 → (2, 4); 1 → (1, 1)."""
-    model = next(m for m in (4, 2, 1) if n_devices % m == 0)
-    return (n_devices // model, model)
+    """(data, model) shape for ``n_devices``, as square as it divides:
+    data = the largest divisor whose square is at most ``n_devices``,
+    model = the rest.  8 → (2, 4); 4 → (2, 2), the chips of a v5e host's
+    2x2 topology, so that both fsdp and tp shard; 1 → (1, 1)."""
+    data = max(d for d in range(1, n_devices + 1)
+               if n_devices % d == 0 and d * d <= n_devices)
+    return (data, n_devices // data)
 
 
 def canonical_placement(placement: Any) -> Tuple[Tuple[str, tuple], ...]:

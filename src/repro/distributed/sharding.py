@@ -32,14 +32,9 @@ __all__ = ["ShardingRules", "make_rules", "spec_for_axes", "tree_shardings",
 
 
 def abstract_mesh(shape=(16, 16), axes=("data", "model")):
-    """Device-less mesh for rule evaluation, across JAX versions: newer
-    ``AbstractMesh`` takes ``(axis_sizes, axis_names)``, 0.4.x takes one
-    ``((name, size), ...)`` tuple."""
+    """Device-less mesh for rule evaluation."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 # logical axis -> mesh axis (or tuple), per shape kind

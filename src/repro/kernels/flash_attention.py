@@ -18,13 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; interpret mode runs without them
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -94,9 +88,9 @@ def flash_attention_folded(q, k, v, *, causal: bool = True, window: int = 0,
 
     # fp32 accumulators in VMEM, persisting across the sequential k grid dim
     scratch_shapes = [
-        _VMEM((block_q, G, D), jnp.float32),
-        _VMEM((block_q, G), jnp.float32),
-        _VMEM((block_q, G), jnp.float32),
+        pltpu.VMEM((block_q, G, D), jnp.float32),
+        pltpu.VMEM((block_q, G), jnp.float32),
+        pltpu.VMEM((block_q, G), jnp.float32),
     ]
 
     return pl.pallas_call(

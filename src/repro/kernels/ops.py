@@ -1,8 +1,10 @@
 """Jit'd model-facing wrappers for the Pallas kernels.
 
-These fold the model layouts into the kernel layouts, pick block sizes, and
-choose interpret mode automatically (CPU backend ⇒ interpret=True, so the
-same model code validates on this container and compiles natively on TPU).
+These fold the model layouts into the kernel layouts and pick block sizes.
+Every kernel compiles natively by default (``interpret=False``): a caller
+that wants the Pallas interpreter, as the CPU tests do, asks for it.  There
+is no device sniffing here, so a kernel that cannot run natively fails
+where it is called instead of quietly running interpreted.
 """
 from __future__ import annotations
 
@@ -16,12 +18,7 @@ from . import rglru_scan as _rg
 from . import rmsnorm as _rn
 from . import wkv6 as _wkv
 
-__all__ = ["flash_attention", "rglru_scan", "wkv6", "rmsnorm",
-           "default_interpret"]
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+__all__ = ["flash_attention", "rglru_scan", "wkv6", "rmsnorm"]
 
 
 @functools.partial(jax.custom_vjp,
@@ -67,29 +64,23 @@ _flash_attention_vjp.defvjp(_flash_fwd, _flash_bwd)
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = None):
+                    interpret: bool = False):
     """q: (B, S, K, G, D); k, v: (B, T, K, D) → (B, S, K, G, D).
     Differentiable: Pallas forward + blockwise online-softmax backward."""
-    if interpret is None:
-        interpret = default_interpret()
     return _flash_attention_vjp(q, k, v, causal, window, block_q, block_k,
                                 interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def rglru_scan(a, b, *, block_t: int = 256, interpret: bool = None):
+def rglru_scan(a, b, *, block_t: int = 128, interpret: bool = False):
     """a, b: (B, T, D) → h (B, T, D) fp32."""
-    if interpret is None:
-        interpret = default_interpret()
     return _rg.rglru_scan(a, b, block_t=block_t, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def wkv6(r, k, v, w, u, *, block_t: int = 64, interpret: bool = None):
+def wkv6(r, k, v, w, u, *, block_t: int = 64, interpret: bool = False):
     """r,k,v,w: (B, T, H, hs); u: (H, hs).
     Returns (o (B,T,H,hs) fp32, state (B,H,hs,hs) fp32)."""
-    if interpret is None:
-        interpret = default_interpret()
     B, T, H, hs = r.shape
     def fold(t):
         return t.transpose(0, 2, 1, 3).reshape(B * H, T, hs)
@@ -103,10 +94,8 @@ def wkv6(r, k, v, w, u, *, block_t: int = 64, interpret: bool = None):
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",
                                              "interpret"))
 def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool = None):
+            interpret: bool = False):
     """x: (..., D); w: (D,)."""
-    if interpret is None:
-        interpret = default_interpret()
     shape = x.shape
     xf = x.reshape(-1, shape[-1])
     n = xf.shape[0]

@@ -15,13 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _scan_block(a, b):
@@ -53,7 +47,7 @@ def _rglru_kernel(a_ref, b_ref, o_ref, h_ref, *, n_t: int):
     h_ref[...] = h[-1:]                # (1, D) carry
 
 
-def rglru_scan(a, b, *, block_t: int = 256, interpret: bool = False):
+def rglru_scan(a, b, *, block_t: int = 128, interpret: bool = False):
     """a, b: (B, T, D) fp32; returns inclusive scan h (B, T, D) fp32."""
     B, T, D = a.shape
     block_t = min(block_t, T)
@@ -69,6 +63,6 @@ def rglru_scan(a, b, *, block_t: int = 256, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((1, block_t, D), lambda b_, t: (b_, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, D), jnp.float32),
-        scratch_shapes=[_VMEM((1, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, D), jnp.float32)],
         interpret=interpret,
     )(a.astype(jnp.float32), b.astype(jnp.float32))

@@ -231,14 +231,19 @@ KERNELS: Dict[str, dict] = {
         "workset": _wkv6_workset,
     },
     "rglru_scan": {
-        "grid": {"block_t": (64, 128, 256)},
-        "defaults": {"block_t": 256},
+        # 256 is absent: at recurrentgemma-2b's width (D = 2560) its fp32
+        # a/b/h tiles need 24.9 MB of VMEM, over v5e's 16 MB scoped limit,
+        # and Mosaic refuses the kernel
+        "grid": {"block_t": (64, 128)},
+        "defaults": {"block_t": 128},
         "validate": _rglru_validate,
         "roofline": _rglru_roofline,
         "workset": _rglru_workset,
     },
     "rmsnorm": {
-        "grid": {"block_rows": (64, 128, 256, 512)},
+        # 512 is absent: at qwen2.5-14b's width (D = 5120, bf16) its row
+        # tiles need 20 MB of VMEM, over v5e's 16 MB scoped limit
+        "grid": {"block_rows": (64, 128, 256)},
         "defaults": {"block_rows": 256},
         "validate": _rmsnorm_validate,
         "roofline": _rmsnorm_roofline,

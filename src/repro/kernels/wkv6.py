@@ -12,7 +12,10 @@ chunked linear-attention form, adapted from the paper's CUDA kernel):
 
 The chunk state S (hs × hs) persists in VMEM scratch across the sequential
 chunk grid dimension.  Layouts (folded in ops.py): r,k,v,w: (BH, T, hs);
-u: (BH, hs) broadcast per head.
+u: (BH, hs) broadcast per head, carried into the kernel as (BH, 1, hs) so
+that its block's last two dims are the array's own (Mosaic refuses a
+(1, hs) block of a (BH, hs) array: its second-to-last dim is neither a
+multiple of 8 nor the full dim).
 """
 from __future__ import annotations
 
@@ -21,13 +24,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+
+def _cumsum_rows(x):
+    """Inclusive prefix sum over axis 0 by Hillis-Steele doubling
+    (log2(L) shifted adds): Mosaic has no lowering for ``cumsum``."""
+    L = x.shape[0]
+    off = 1
+    while off < L:
+        x = x + jnp.pad(x, ((off, 0), (0, 0)))[:L]
+        off *= 2
+    return x
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sout_ref, s_ref,
@@ -46,7 +54,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sout_ref, s_ref,
 
     # exclusive cumulative product of decays (log-space for stability)
     logw = jnp.log(jnp.maximum(w, 1e-38))
-    cum = jnp.cumsum(logw, axis=0)         # inclusive
+    cum = _cumsum_rows(logw)               # inclusive
     p_incl = jnp.exp(cum)                  # P_{t+1} = ∏_{s<=t} w_s
     p_excl = jnp.exp(cum - logw)           # P_t     = ∏_{s<t}  w_s
 
@@ -71,9 +79,9 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sout_ref, s_ref,
     o = inter + intra + diag[:, None] * v
     o_ref[0] = o.astype(o_ref.dtype)
 
-    p_last = p_incl[-1]                                # (hs,)
-    kp = k_t * p_last[None, :]
-    s_new = p_last[:, None] * s0 + jax.lax.dot_general(
+    p_last = p_incl[L - 1:]                            # (1, hs)
+    kp = k_t * p_last
+    s_new = p_last.T * s0 + jax.lax.dot_general(
         kp, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     s_ref[...] = s_new
@@ -100,7 +108,7 @@ def wkv6_folded(r, k, v, w, u, *, block_t: int = 64,
             pl.BlockSpec((1, block_t, hs), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_t, hs), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_t, hs), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, hs), lambda b, t: (b, 0)),
+            pl.BlockSpec((1, 1, hs), lambda b, t: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_t, hs), lambda b, t: (b, t, 0)),
@@ -110,7 +118,7 @@ def wkv6_folded(r, k, v, w, u, *, block_t: int = 64,
             jax.ShapeDtypeStruct((BH, T, hs), jnp.float32),
             jax.ShapeDtypeStruct((BH, hs, hs), jnp.float32),
         ],
-        scratch_shapes=[_VMEM((hs, hs), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(BH, 1, hs))
     return o, s_out

@@ -14,13 +14,8 @@ __all__ = ["make_production_mesh", "make_mesh"]
 
 
 def _make(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older make_mesh has no
-    # axis_types kwarg (everything is implicitly Auto there).
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced as reduce_cfg
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Transformer
 
 
@@ -100,6 +101,7 @@ def run_engine(cfg, *, n_requests: int, rate_rps: float, capacity: int,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

@@ -31,6 +31,7 @@ import jax
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.data import PrefetchIterator, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Transformer
 from repro.optim import default_optimizer
 from repro.runtime import FaultInjector, StepWatchdog
@@ -101,6 +102,7 @@ def train(cfg, *, steps: int, batch: int, seq: int, ckpt_dir: str,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",

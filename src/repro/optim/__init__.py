@@ -3,7 +3,7 @@ from .adafactor import adafactor
 from .adamw import Optimizer, adamw
 from .offload import (attention_step_program, host_memory_kind,
                       offload_shardings, offloaded_optimizer,
-                      plan_step_program, supports_pinned_host)
+                      plan_step_program)
 
 
 def default_optimizer(cfg) -> Optimizer:
@@ -18,4 +18,4 @@ def default_optimizer(cfg) -> Optimizer:
 __all__ = ["adamw", "adafactor", "Optimizer", "default_optimizer",
            "offload_shardings", "offloaded_optimizer",
            "plan_step_program", "attention_step_program",
-           "host_memory_kind", "supports_pinned_host"]
+           "host_memory_kind"]

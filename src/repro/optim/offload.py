@@ -8,12 +8,10 @@ next step's forward).  Concretely this is just a sharding transform — the
 jitted step's in/out shardings for the optimizer state carry
 ``memory_kind="pinned_host"`` and XLA inserts the transfers.
 
-JAX-version compatibility: the memory-space API has moved around
-(``jax.memory.Space`` is newer than some installed jaxlibs, and CPU builds
-expose no ``pinned_host`` space at all), so this module probes what the
-runtime actually supports — ``host_memory_kind()`` returns the usable host
-kind or ``None`` — and every transform degrades to an identity when host
-memory is unavailable, keeping one code path for CPU CI and TPU prod.
+``host_memory_kind()`` says whether the device has a ``pinned_host``
+space (TPU does, and so does the CPU backend of the installed JAX).  The
+transforms below require it: on a device without one they raise, instead
+of quietly leaving the optimizer state on the device.
 
 ``offload_shardings`` converts a device sharding tree; ``plan_step_program``
 builds the equivalent explicit block-``Program`` (host update blocks +
@@ -32,59 +30,45 @@ import jax
 from repro.core import Program
 
 __all__ = ["offload_shardings", "offloaded_optimizer", "plan_step_program",
-           "attention_step_program", "host_memory_kind",
-           "supports_pinned_host"]
+           "attention_step_program", "host_memory_kind"]
 
 _HOST_KIND = "pinned_host"
 
 
 @functools.lru_cache(maxsize=None)
 def _device_memory_kinds(device) -> tuple:
-    try:
-        return tuple(m.kind for m in device.addressable_memories())
-    except Exception:
-        return ()
+    return tuple(m.kind for m in device.addressable_memories())
 
 
 def host_memory_kind(device=None) -> Optional[str]:
     """The host-side memory kind usable for offload on ``device``, or
-    ``None`` when the platform has no addressable host space distinct from
-    its default memory (e.g. CPU jaxlib: everything is unpinned_host)."""
+    ``None`` when the device addresses no ``pinned_host`` space."""
     if device is None:
         device = jax.devices()[0]
     return _HOST_KIND if _HOST_KIND in _device_memory_kinds(device) else None
 
 
-def supports_pinned_host(device=None) -> bool:
-    return host_memory_kind(device) is not None
-
-
-def _transfer_to(kind: str):
-    """A placement target usable inside jit, across JAX versions."""
-    space = getattr(jax, "memory", None)
-    if space is not None and hasattr(space, "Space"):
-        return space.Space.Host if kind == _HOST_KIND else space.Space.Device
-    ttmk = getattr(jax.sharding, "TransferToMemoryKind", None)
-    if ttmk is None:
-        from jax._src.sharding_impls import TransferToMemoryKind as ttmk
-    return ttmk(kind)
+def _require_host_kind() -> str:
+    kind = host_memory_kind()
+    if kind is None:
+        raise RuntimeError(
+            f"{jax.devices()[0]} has no {_HOST_KIND!r} memory: optimizer "
+            f"state cannot be offloaded to host on this device")
+    return kind
 
 
 def offload_shardings(sharding_tree):
-    """Move a sharding tree's memory kind to the host space; identity when
-    the platform has none (the optimizer then simply stays on device)."""
-    kind = host_memory_kind()
-    if kind is None:
-        return sharding_tree
+    """Move a sharding tree's memory kind to the host space."""
+    kind = _require_host_kind()
     return jax.tree.map(
         lambda s: s.with_memory_kind(kind), sharding_tree,
         is_leaf=lambda x: isinstance(x, jax.sharding.Sharding))
 
 
 def _to_space(tree, kind: str):
-    if host_memory_kind() is None:
-        return tree     # single memory space: nothing to move
-    target = _transfer_to(kind)
+    _require_host_kind()
+    target = (jax.memory.Space.Host if kind == _HOST_KIND
+              else jax.memory.Space.Device)
     return jax.tree.map(
         lambda x: jax.device_put(x, target)
         if hasattr(x, "ndim") and x.ndim > 0 else x, tree)
@@ -137,24 +121,34 @@ def plan_step_program(n_steps: int = 4) -> Program:
     return p
 
 
-def attention_step_program(n_steps: int = 2) -> Program:
+def attention_step_program(n_steps: int = 2, *, batch: int = 1,
+                           seq: int = 128, kv_heads: int = 1,
+                           group: int = 1, head_dim: int = 8,
+                           dtype=None, interpret: bool = False) -> Program:
     """A flash-attention train step as a block program with a *tagged*
     Pallas kernel block: the ``kernel="flash_attention"`` tag lets the
     plan-space tuner enumerate tile variants (``block_q``/``block_k``)
     for the attention launch and price them with the two-level roofline,
-    alongside the usual policy/stream/fuse axes.  Shapes are kept small
-    (S = T = 128) so interpret-mode Pallas stays fast on CPU CI while
-    the clamped tile grid still yields >= 3 distinct variants."""
+    alongside the usual policy/stream/fuse axes.
+
+    q is (batch, seq, kv_heads, group, head_dim) and k, v are
+    (batch, seq, kv_heads, head_dim), in ``dtype`` (float32 when None).
+    The defaults (S = T = 128, one head of 8) keep interpret-mode Pallas
+    fast on the CPU while the clamped tile grid still yields >= 3
+    distinct variants; qwen2.5-14b's layout is ``kv_heads=8, group=5,
+    head_dim=128``.  ``interpret`` is passed to the kernel as is: the
+    CPU needs ``True``, a TPU runs it natively."""
     import numpy as np
 
     from repro.kernels import ops
 
-    B, S, T, K, G, D = 1, 128, 128, 1, 1, 8
+    dtype = np.float32 if dtype is None else dtype
+    B, S, K, G, D = batch, seq, kv_heads, group, head_dim
     rng = np.random.default_rng(0)
     p = Program("attention_step")
-    p.bind("q", rng.standard_normal((B, S, K, G, D)).astype(np.float32))
-    p.bind("k", rng.standard_normal((B, T, K, D)).astype(np.float32))
-    p.bind("v", rng.standard_normal((B, T, K, D)).astype(np.float32))
+    p.bind("q", rng.standard_normal((B, S, K, G, D)).astype(dtype))
+    p.bind("k", rng.standard_normal((B, S, K, D)).astype(dtype))
+    p.bind("v", rng.standard_normal((B, S, K, D)).astype(dtype))
     p.bind("gain", np.ones((1,), np.float32))
 
     p.host(lambda xp, gain: {"g": gain * 1.001},
@@ -165,11 +159,14 @@ def attention_step_program(n_steps: int = 2) -> Program:
         p.offload(lambda xp, q, k, v, *, block_q=128, block_k=128:
                   {"o": ops.flash_attention(q, k, v, causal=True,
                                             block_q=block_q,
-                                            block_k=block_k)},
+                                            block_k=block_k,
+                                            interpret=interpret)},
                   reads=("q", "k", "v"), writes=("o",),
                   name="attention", kernel="flash_attention")
+        # the sum of squares accumulates in float32 whatever o's dtype
         p.offload(lambda xp, o, g:
-                  {"loss": (o * o).sum().reshape(1) * g},
+                  {"loss": xp.square(o.astype(xp.float32)).sum()
+                   .reshape(1) * g},
                   reads=("o", "g"), writes=("loss",), name="reduce")
     p.host(lambda xp, loss: {"final_loss": loss},
            reads=("loss",), writes=("final_loss",), name="log_metrics")

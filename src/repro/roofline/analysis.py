@@ -16,8 +16,9 @@ multipliers through while/call/fusion/conditional edges.  With that:
     activations incl. remat recompute, or KV-cache reads for decode),
     because fusion-internal traffic is not recoverable from HLO text.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per the assignment).
+Hardware constants: ``PEAKS`` keyed by ``jax.Device.device_kind``;
+``hw_for(device)`` selects an entry and refuses a device that has none.
+``HW`` is the TPU v5e entry, the target of the dry-run reports.
 """
 from __future__ import annotations
 
@@ -25,7 +26,8 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["HW", "CALIBRATABLE", "ENERGY_TERMS", "PREDICTOR_FEATURES",
+__all__ = ["HW", "PEAKS", "hw_for", "CALIBRATABLE", "ENERGY_TERMS",
+           "PREDICTOR_FEATURES",
            "parse_hlo", "collective_bytes",
            "dot_flops", "analytic_model_flops", "analytic_hbm_bytes",
            "roofline_terms", "offload_cost_terms", "kernel_roofline_terms",
@@ -33,7 +35,11 @@ __all__ = ["HW", "CALIBRATABLE", "ENERGY_TERMS", "PREDICTOR_FEATURES",
            "candidate_features", "fit_candidate_predictor",
            "predict_candidate_s"]
 
-HW = {
+# TPU v5e, per chip (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s
+# bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect
+# (4 links of 50 GB/s).  The offload and energy constants below the peaks
+# are the cost model's own, not published figures.
+_TPU_V5E = {
     "peak_flops_bf16": 197e12,   # per chip
     "hbm_bw": 819e9,             # bytes/s per chip
     "ici_bw": 50e9,              # bytes/s per link
@@ -56,6 +62,27 @@ HW = {
     "ici_j_per_byte": 2.5e-11,
     "flop_j": 1.5e-13,
 }
+
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": _TPU_V5E,
+    # The host CPU that runs the tests prices plans with the v5e entry, so
+    # that plans, goldens and tuning fingerprints made there are the ones
+    # the chip would price.  These are not a CPU's peaks.
+    "cpu": _TPU_V5E,
+}
+
+HW = PEAKS["TPU v5 lite"]
+
+
+def hw_for(device) -> Dict[str, float]:
+    """The pricing constants for ``device`` (a ``jax.Device``), looked up
+    by its ``device_kind``.  A device of a kind with no entry raises: a
+    plan is never priced with another chip's peaks."""
+    kind = device.device_kind
+    if kind not in PEAKS:
+        raise KeyError(f"no peaks for device kind {kind!r}; known: "
+                       f"{sorted(PEAKS)}")
+    return PEAKS[kind]
 
 # the energy-model constants (a documented subset of HW; override via
 # ``hw=`` to recalibrate for a different part)

@@ -87,17 +87,27 @@ class ServeRuntime:
         self.exact_buckets = cfg.layer_pattern in ("rwkv", "griffin")
 
         # weights resident once, through the instrumented residency layer
-        if params is None:
-            params = self.model.init(jax.random.key(seed))
+        owned = params is None
+        if owned:
+            # one compiled init: no fp32 temporaries of whole stacked
+            # leaves beside the weights already made
+            params = jax.jit(self.model.init)(jax.random.key(seed))
         self.residency = DeviceResidency(backend=self.be)
         leaves, treedef = jax.tree.flatten(params)
+        del params
         for i, leaf in enumerate(leaves):
             self.residency.put_host(f"w{i:04d}", np.asarray(leaf))
-        for i in range(len(leaves)):
+            if owned:
+                # drop the init copy at once: the device never holds two
+                # weight sets (rwkv6-3b: 6.1 GB each on a 16 GB chip)
+                leaf.delete()
+        n_leaves = len(leaves)
+        del leaves
+        for i in range(n_leaves):
             self.residency.prefetch(f"w{i:04d}")   # advancedload, async
         self.params = jax.tree.unflatten(
             treedef, [self.residency.device_value(f"w{i:04d}")
-                      for i in range(len(leaves))])
+                      for i in range(n_leaves)])
         self.weights_bytes = self.residency.stats.h2d_bytes
 
         self._prefill = jax.jit(

@@ -152,13 +152,22 @@ class TestBackends:
         assert len({d.stream for d in loads}) == 1
 
     def test_pinned_backend_degrades_on_cpu(self):
-        """On platforms without a pinned_host space the pinned backend is
-        still a correct JaxDeviceBackend."""
+        """The CPU device has a pinned_host space too: the pinned backend
+        stages the round trip through it and computes on device memory."""
         be = PinnedHostBackend()
+        assert be._pinned.memory_kind == "pinned_host"
         x = np.arange(16, dtype=np.float32)
         h = be.upload(x, stream=1)
         be.sync(1)
+        assert h.sharding.memory_kind == "device"
         np.testing.assert_array_equal(be.download(h), x)
+
+    def test_pinned_backend_refuses_device_without_pinned_space(
+            self, monkeypatch):
+        import repro.optim.offload as offload
+        monkeypatch.setattr(offload, "host_memory_kind", lambda dev: None)
+        with pytest.raises(RuntimeError, match="pinned_host"):
+            PinnedHostBackend()
 
     def test_get_backend_memoizes_by_name(self):
         assert get_backend() is get_backend()

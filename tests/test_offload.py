@@ -2,10 +2,11 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (emit, execute, naive_plan, plan)
 from repro.optim import (adamw, host_memory_kind, offload_shardings,
-                         plan_step_program, supports_pinned_host)
+                         plan_step_program)
 
 
 def test_train_loop_program_schedule():
@@ -35,18 +36,20 @@ def test_train_loop_results_match_oracle():
                                rtol=1e-5)
 
 
-def test_offload_shardings_memory_kind():
-    """Platforms with a pinned_host space get host-kind shardings; CPU
-    jaxlib (single memory space) degrades to the identity transform."""
+def test_offload_shardings_memory_kind(monkeypatch):
+    """The device's pinned_host space (the CPU of the installed JAX has
+    one too) becomes the shardings' memory kind; a device without one
+    is refused, not left on device."""
+    import repro.optim.offload as offload
+    assert host_memory_kind() == "pinned_host"
     sh = jax.sharding.SingleDeviceSharding(jax.devices()[0])
     tree = {"m": sh, "v": {"x": sh}}
     off = offload_shardings(tree)
-    if supports_pinned_host():
-        assert off["m"].memory_kind == "pinned_host"
-        assert off["v"]["x"].memory_kind == "pinned_host"
-    else:
-        assert host_memory_kind() is None
-        assert off["m"] is sh and off["v"]["x"] is sh
+    assert off["m"].memory_kind == "pinned_host"
+    assert off["v"]["x"].memory_kind == "pinned_host"
+    monkeypatch.setattr(offload, "host_memory_kind", lambda device=None: None)
+    with pytest.raises(RuntimeError, match="pinned_host"):
+        offload_shardings(tree)
 
 
 def test_offloaded_optimizer_step_compiles_and_runs():
@@ -61,33 +64,26 @@ def test_offloaded_optimizer_step_compiles_and_runs():
     state = base.init(params)
     grads = {"w": jnp.full((32, 32), 0.5, jnp.float32)}
 
-    if supports_pinned_host():
-        dev = jax.devices()[0]
-        d_sh = jax.sharding.SingleDeviceSharding(dev)
-        h_sh = d_sh.with_memory_kind("pinned_host")
-        host_state = jax.tree.map(
-            lambda x: jax.device_put(x, h_sh) if hasattr(x, "shape") and
-            x.ndim > 0 else x, state)
+    dev = jax.devices()[0]
+    d_sh = jax.sharding.SingleDeviceSharding(dev)
+    h_sh = d_sh.with_memory_kind("pinned_host")
+    host_state = jax.tree.map(
+        lambda x: jax.device_put(x, h_sh) if hasattr(x, "shape") and
+        x.ndim > 0 else x, state)
 
-        state_sh = jax.tree.map(
-            lambda x: h_sh if hasattr(x, "ndim") and x.ndim > 0 else d_sh,
-            state)
-        f = jax.jit(lambda p, s, g: opt.update(g, s, p),
-                    in_shardings=(d_sh, state_sh, d_sh),
-                    out_shardings=(d_sh, state_sh))
-        # the CPU backend cannot LOAD placement-annotation custom calls, so
-        # the criterion here is lowering with the host-memory annotations
-        # present (real compile+run happens on TPU; the pinned_host
-        # transfers themselves are exercised by the DeviceResidency path)
-        lowered = f.lower(params, host_state, grads)
-        hlo = lowered.as_text()
-        assert "pinned_host" in hlo or "annotate_device_placement" in hlo
-    else:
-        # single-memory-space platform: the offloaded update must still
-        # compile and run (identity placement), proving the fallback works
-        new_p_off, _ = jax.jit(lambda p, s, g: opt.update(g, s, p))(
-            params, state, grads)
-        assert np.isfinite(np.asarray(new_p_off["w"])).all()
+    state_sh = jax.tree.map(
+        lambda x: h_sh if hasattr(x, "ndim") and x.ndim > 0 else d_sh,
+        state)
+    f = jax.jit(lambda p, s, g: opt.update(g, s, p),
+                in_shardings=(d_sh, state_sh, d_sh),
+                out_shardings=(d_sh, state_sh))
+    # the CPU backend cannot LOAD placement-annotation custom calls, so
+    # the criterion here is lowering with the host-memory annotations
+    # present (real compile+run happens on TPU; the pinned_host
+    # transfers themselves are exercised by the DeviceResidency path)
+    lowered = f.lower(params, host_state, grads)
+    hlo = lowered.as_text()
+    assert "pinned_host" in hlo or "annotate_device_placement" in hlo
 
     # numerics of the offloaded update == base update (plain placement)
     new_p, _ = jax.jit(lambda p, s, g: base.update(g, s, p))(params, state,

@@ -111,8 +111,9 @@ class TestParetoFrontier:
         time (big block_q → fewer passes) against on-chip residency
         (small tiles) — ≥2 non-dominated points, and the time-optimal
         and memory-optimal winners are different plans."""
-        pl = tune(attention_step_program(n_steps=1), backend="numpy",
-                  measure=False, cache=False, use_calibration=False)
+        pl = tune(attention_step_program(n_steps=1, interpret=True),
+                  backend="numpy", measure=False, cache=False,
+                  use_calibration=False)
         t = pl.meta["tuning"]
         assert len(t["pareto"]) >= 2
         assert t["winners"]["time"] != t["winners"]["memory"]
@@ -122,7 +123,7 @@ class TestParetoFrontier:
                 < by_label[t["winners"]["time"]]["peak_bytes"])
 
     def test_objective_selects_winner_and_weighted_mix(self):
-        prog = attention_step_program(n_steps=1)
+        prog = attention_step_program(n_steps=1, interpret=True)
         for obj in ("energy", "memory"):
             pl = tune(prog, backend="numpy", measure=False, cache=False,
                       use_calibration=False, objective=obj)

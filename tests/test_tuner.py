@@ -400,7 +400,7 @@ class TestKernelAxis:
 
     def _tuned(self, **kw):
         from repro.optim import attention_step_program
-        p = attention_step_program(n_steps=1)
+        p = attention_step_program(n_steps=1, interpret=True)
         kw = dict(self.GRID, reps=1, **kw)
         return p, plan(p, policy="auto", **kw)
 
@@ -478,9 +478,9 @@ class TestKernelAxis:
         from repro.core import TuneCache
         from repro.optim import attention_step_program
         tc = TuneCache(tmp_path / "kv")
-        p1 = attention_step_program(n_steps=1)
+        p1 = attention_step_program(n_steps=1, interpret=True)
         pl1 = tune(p1, reps=1, cache=tc, **self.GRID)
-        p2 = attention_step_program(n_steps=1)
+        p2 = attention_step_program(n_steps=1, interpret=True)
         pl2 = tune(p2, reps=1, cache=tc, **self.GRID)
         assert pl2.meta["tuning_cache"]["hit"] is True
         assert pl2.meta["tuning"] == pl1.meta["tuning"]

@@ -133,7 +133,7 @@ class TestGoldenDiagnostics:
 
     def test_illegal_kernel_tile(self):
         from repro.optim import attention_step_program
-        p = attention_step_program(n_steps=1)
+        p = attention_step_program(n_steps=1, interpret=True)
         pl = plan(p)
         rep = verify_plan(
             pl, kernel_variants={"flash_attention":

@@ -47,8 +47,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .backend import Backend
 from .executor import (ExecStats, PlanExecutionError, _nest, _run_block,
-                       _Slot, do_load, do_release, do_store, do_sync,
-                       dummy_arg, kernel_fn)
+                       _Slot, _span, do_load, do_release, do_store, do_sync,
+                       dummy_arg, implicit_upload, kernel_fn)
 from .ir import (AdvancedLoad, BlockKind, Callsite, DelegateStore, GroupDecl,
                  Plan, PlanOp, Program, Release, Synchronize)
 
@@ -78,6 +78,7 @@ class _Segment:
     blocks: List[int]
     n_stores: int
     final_writes: Tuple[str, ...]
+    names: str = ""               # "mm_E+mm_F": the launch span's blocks
     fused: Optional[Callable[..., Tuple[Any, ...]]] = None
 
 
@@ -132,7 +133,8 @@ def _build_segment(run: List[PlanOp], program: Program) -> _Segment:
                     writes_order.append(w)
 
     return _Segment(items=items, arg_spec=arg_spec, blocks=blocks,
-                    n_stores=n_stores, final_writes=tuple(writes_order))
+                    n_stores=n_stores, final_writes=tuple(writes_order),
+                    names="+".join(program.blocks[i].name for i in blocks))
 
 
 def _replay_block(blk, xp, env: Dict[str, Any], get_dummy,
@@ -421,8 +423,7 @@ class CompiledPlan:
                     raise PlanExecutionError(
                         f"fused loop reads {v!r}: not on device "
                         "(missing advancedload)")
-                slot.device = be.upload(slot.host, name=v)
-                slot.valid_device = True
+                implicit_upload(slot, v, be)
             carry[v] = slot.device
 
         # rewritten entry vars are safe to donate: after the launch the
@@ -430,8 +431,9 @@ class CompiledPlan:
         donate = tuple(v for tag, v in seg.arg_spec
                        if tag == "entry" and v in seg.final_writes)
         t = time.perf_counter()
-        out = be.launch_loop(node.body_fn, node.n_iters, carry,
-                             donate_keys=donate)
+        with _span("hmpp.callsite", blocks=seg.names):
+            out = be.launch_loop(node.body_fn, node.n_iters, carry,
+                                 donate_keys=donate)
         stats.kernel_time += time.perf_counter() - t
         stats.kernel_calls += len(seg.blocks) * node.logical_iters
         stats.fused_launches += 1
@@ -445,11 +447,9 @@ class CompiledPlan:
         # once per iteration for parity with the interpreter
         for it in seg.items:
             if it[0] == "sync":
-                d = it[1]
-                t = time.perf_counter()
-                be.sync(d.stream)
-                be.sync(0)
-                stats.sync_time += time.perf_counter() - t
+                with _span("hmpp.synchronize"):
+                    be.sync(it[1].stream)
+                    be.sync(0)
                 stats.syncs += node.logical_iters
 
     def _run_segment(self, seg: _Segment, env, stats: ExecStats,
@@ -485,13 +485,13 @@ class CompiledPlan:
                     raise PlanExecutionError(
                         f"compiled segment reads {v!r}: not on device "
                         "(missing advancedload)")
-                slot.device = be.upload(slot.host, name=v)
-                slot.valid_device = True
+                implicit_upload(slot, v, be)
             args.append(slot.device)
 
         # 3. one fused launch for the whole segment -----------------------
         t = time.perf_counter()
-        outs = seg.fused(*args)
+        with _span("hmpp.callsite", blocks=seg.names):
+            outs = seg.fused(*args)
         stats.kernel_time += time.perf_counter() - t
         stats.kernel_calls += len(seg.blocks)   # logical count parity
         stats.fused_launches += 1

@@ -5,8 +5,10 @@ The paper's generated HMPP code runs on CPU+GPU; here "host" is numpy and
 the default JAX device space, a ``pinned_host``-staged variant, or a pure
 numpy simulation.  The driver walks a ``Plan``, runs host blocks with
 numpy, dispatches offload blocks and transfers through the backend ONLY
-where the plan says so — transfer counts/bytes/wall times are recorded,
-which is exactly what the paper's Figs. 4-6 measure.
+where the plan says so — transfer counts and bytes are recorded, which is
+exactly what the paper's Figs. 4-6 measure, and each directive runs inside
+a host span named after it (``hmpp.advancedload``, ``hmpp.callsite``, ...)
+that a profiler trace puts on the device's clock.
 
 Two execution modes:
 
@@ -34,6 +36,7 @@ import time
 from typing import Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .backend import Backend, get_backend
 from .ir import (AdvancedLoad, BlockKind, Callsite, DelegateStore, GroupDecl,
@@ -54,11 +57,7 @@ class ExecStats:
     host_calls: int = 0
     syncs: int = 0
     fused_launches: int = 0     # compiled mode: actual jit invocations
-    h2d_time: float = 0.0
-    d2h_time: float = 0.0
     kernel_time: float = 0.0
-    host_time: float = 0.0
-    sync_time: float = 0.0
     wall_time: float = 0.0
     compile_time: float = 0.0   # one-time plan lowering (compiled mode);
                                 # NOT folded into wall_time
@@ -75,6 +74,16 @@ class ExecStats:
                 "kernel_calls": self.kernel_calls,
                 "host_calls": self.host_calls,
                 "syncs": self.syncs}
+
+
+def _span(name: str, **args) -> TraceAnnotation:
+    """A host span in the profiler's trace, named after the HMPP directive
+    the executor performs inside it (``hmpp.advancedload``, ...), with
+    ``args`` as its stats.  The profiler puts host spans on the clock of
+    the device planes, so a trace splits an execution into upload, device
+    and download time; while no profiler records, a span is a TraceMe that
+    costs about a microsecond."""
+    return TraceAnnotation(name, **args)
 
 
 @dataclasses.dataclass
@@ -159,6 +168,14 @@ def execute(p: Plan, inputs: Optional[Dict[str, np.ndarray]] = None,
     excluded from ``stats.wall_time``, so first-call and steady-state runs
     report comparable wall times.
     """
+    with _span("hmpp.execute", mode=mode):
+        return _execute(p, inputs, check, mode, backend, fuse_loops,
+                        kernel_variants, verify)
+
+
+def _execute(p, inputs, check, mode, backend, fuse_loops, kernel_variants,
+             verify):
+    """The body of ``execute``, inside its ``hmpp.execute`` span."""
     if mode not in ("interpreted", "compiled"):
         raise ValueError(f"unknown execution mode {mode!r}")
     if fuse_loops is None:
@@ -212,8 +229,9 @@ def execute(p: Plan, inputs: Optional[Dict[str, np.ndarray]] = None,
         if compiled is None or compiled.backend is not be \
                 or fp != fingerprint:
             tc = time.perf_counter()
-            compiled = compile_plan(p, be, fuse_loops=fuse_loops,
-                                    kernel_variants=kernel_variants)
+            with _span("hmpp.lower"):
+                compiled = compile_plan(p, be, fuse_loops=fuse_loops,
+                                        kernel_variants=kernel_variants)
             stats.compile_time = time.perf_counter() - tc
             cache[key] = (compiled, fingerprint)
         t0 = time.perf_counter()
@@ -236,7 +254,9 @@ def execute(p: Plan, inputs: Optional[Dict[str, np.ndarray]] = None,
                 raise PlanExecutionError(
                     f"output {name!r} not on host at program end "
                     "(missing delegatestore)")
-            slot.host = be.download(slot.device)
+            with _span("hmpp.delegatestore", var=name,
+                       bytes=_nbytes(slot.device)):
+                slot.host = be.download(slot.device)
             slot.valid_host = True
         outs[name] = slot.host
     return outs, stats
@@ -287,13 +307,21 @@ def do_load(d: AdvancedLoad, env, stats: ExecStats, be: Backend) -> Any:
     if not slot.valid_host:
         raise PlanExecutionError(
             f"advancedload {d.var!r}: no valid host copy")
-    t = time.perf_counter()
-    slot.device = be.upload(slot.host, stream=d.stream, name=d.var)
-    stats.h2d_time += time.perf_counter() - t
+    nbytes = _nbytes(slot.host)
+    with _span("hmpp.advancedload", var=d.var, bytes=nbytes):
+        slot.device = be.upload(slot.host, stream=d.stream, name=d.var)
     stats.h2d_transfers += 1
-    stats.h2d_bytes += _nbytes(slot.host)
+    stats.h2d_bytes += nbytes
     slot.valid_device = True
     return slot.device
+
+
+def implicit_upload(slot: _Slot, var: str, be: Backend) -> None:
+    """The upload a run with ``check=False`` makes where the plan has no
+    advancedload for a device read (not counted in ``ExecStats``)."""
+    with _span("hmpp.advancedload", var=var, bytes=_nbytes(slot.host)):
+        slot.device = be.upload(slot.host, name=var)
+    slot.valid_device = True
 
 
 def do_store(d: DelegateStore, env, stats: ExecStats, be: Backend,
@@ -306,19 +334,18 @@ def do_store(d: DelegateStore, env, stats: ExecStats, be: Backend,
             raise PlanExecutionError(
                 f"delegatestore {d.var!r}: no valid device copy")
         handle = slot.device
-    t = time.perf_counter()
-    slot.host = be.download(handle, stream=d.stream)
-    stats.d2h_time += time.perf_counter() - t
+    nbytes = _nbytes(handle)
+    with _span("hmpp.delegatestore", var=d.var, bytes=nbytes):
+        slot.host = be.download(handle, stream=d.stream)
     stats.d2h_transfers += 1
-    stats.d2h_bytes += _nbytes(slot.host)
+    stats.d2h_bytes += nbytes
     slot.valid_host = True
 
 
 def do_sync(d: Synchronize, stats: ExecStats, be: Backend) -> None:
-    t = time.perf_counter()
-    be.sync(d.stream)     # the transfer queue this callsite's group uses
-    be.sync(0)            # and the compute stream the callsite ran on
-    stats.sync_time += time.perf_counter() - t
+    with _span("hmpp.synchronize"):
+        be.sync(d.stream)     # the transfer queue this callsite's group uses
+        be.sync(0)            # and the compute stream the callsite ran on
     stats.syncs += 1
 
 
@@ -347,12 +374,13 @@ def do_release(d: Optional[Release], env, be: Backend,
         slots = [env[v] for v in names if v in env]
     else:
         slots = list(env.values())
-    for slot in slots:
-        if slot.valid_host:
-            if slot.device is not None:
-                be.free(slot.device)
-            slot.device = None
-            slot.valid_device = False
+    with _span("hmpp.release"):
+        for slot in slots:
+            if slot.valid_host:
+                if slot.device is not None:
+                    be.free(slot.device)
+                slot.device = None
+                slot.valid_device = False
 
 
 def run_directive(d, env, stats: ExecStats, check: bool,
@@ -394,12 +422,12 @@ def _run_block(program: Program, idx: int, env: Dict[str, _Slot],
                     raise PlanExecutionError(
                         f"codelet {blk.name!r} reads {v!r}: not on device "
                         "(missing advancedload)")
-                slot.device = be.upload(slot.host, name=v)
-                slot.valid_device = True
+                implicit_upload(slot, v, be)
             args.append(slot.device)
         t = time.perf_counter()
-        outs = be.launch(kernel_fn(blk, variants), blk.reads, blk.writes,
-                         args)
+        with _span("hmpp.callsite", blocks=blk.name):
+            outs = be.launch(kernel_fn(blk, variants), blk.reads, blk.writes,
+                             args)
         stats.kernel_time += time.perf_counter() - t
         stats.kernel_calls += 1
         for w, val in zip(blk.writes, outs):
@@ -419,12 +447,12 @@ def _run_block(program: Program, idx: int, env: Dict[str, _Slot],
                     raise PlanExecutionError(
                         f"host block {blk.name!r} reads {v!r}: not on host "
                         "(missing delegatestore)")
-                slot.host = be.download(slot.device)
+                with _span("hmpp.delegatestore", var=v,
+                           bytes=_nbytes(slot.device)):
+                    slot.host = be.download(slot.device)
                 slot.valid_host = True
             kwargs[v] = slot.host
-        t = time.perf_counter()
         outs = blk.fn(np, **kwargs)
-        stats.host_time += time.perf_counter() - t
         stats.host_calls += 1
         for w in blk.writes:
             slot = env.setdefault(w, _Slot())

@@ -7,7 +7,7 @@ copy, or both, and performs transfers lazily with the paper's policy:
 uploads as early as the caller schedules them (``prefetch`` = advancedload),
 downloads as late as possible (``fetch`` only when the host actually reads =
 delegatestore), and no transfer at all when the requested space already holds
-a valid copy (noupdate).  All movement is instrumented.
+a valid copy (noupdate).  Every transfer is counted (``ResidencyStats``).
 
 Transfers go through a pluggable ``Backend`` (``repro.core.backend``), so
 prefetches are enqueued asynchronously on a per-entry transfer stream and
@@ -16,7 +16,6 @@ prefetches are enqueued asynchronously on a per-entry transfer stream and
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Optional
 
 import numpy as np
@@ -33,8 +32,6 @@ class ResidencyStats:
     d2h_transfers: int = 0
     d2h_bytes: int = 0
     elided: int = 0
-    h2d_time: float = 0.0
-    d2h_time: float = 0.0
 
 
 @dataclasses.dataclass
@@ -78,9 +75,7 @@ class DeviceResidency:
         if e.valid_host:
             self.stats.elided += 1
             return e.host
-        t = time.perf_counter()
         e.host = self._backend.download(e.device, stream=e.stream)
-        self.stats.d2h_time += time.perf_counter() - t
         self.stats.d2h_transfers += 1
         self.stats.d2h_bytes += _leaf_bytes(e.host)
         e.valid_host = True
@@ -101,9 +96,7 @@ class DeviceResidency:
         if e.valid_device:
             self.stats.elided += 1
             return
-        t = time.perf_counter()
         e.device = self._backend.upload(e.host, stream=e.stream)
-        self.stats.h2d_time += time.perf_counter() - t
         self.stats.h2d_transfers += 1
         self.stats.h2d_bytes += _leaf_bytes(e.host)
         e.valid_device = True

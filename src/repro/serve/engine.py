@@ -110,17 +110,13 @@ class ServeRuntime:
                       for i in range(n_leaves)])
         self.weights_bytes = self.residency.stats.h2d_bytes
 
-        self._prefill = jax.jit(
-            lambda p, b, lp: self.model.prefill(
-                p, b, max_seq=self.max_seq, last_pos=lp))
+        self._prefill = jax.jit(self._prefill_impl)
         self._decode = jax.jit(self._decode_impl,
                                donate_argnums=(1, 2, 3, 4, 5))
         self._admit = jax.jit(self._admit_impl, donate_argnums=(1, 2, 3, 4))
         # park a finished row's tokens device-side so its slot can be
         # reused WITHOUT a host sync; everything downloads in one batch
-        self._park = jax.jit(
-            lambda park, out, slot, idx: park.at[idx].set(out[slot]),
-            donate_argnums=(0,))
+        self._park = jax.jit(self._park_impl, donate_argnums=(0,))
         self._jnp = jnp
 
         # bucket -> "measured" | "cached"; persisted across processes via
@@ -130,7 +126,11 @@ class ServeRuntime:
         self.tune_measurements = 0
         self.tune_hits = 0
 
-    # -- jitted bodies -------------------------------------------------------
+    # -- jitted bodies (each jit is named after its method) ------------------
+    def _prefill_impl(self, params, batch, last_pos):
+        return self.model.prefill(params, batch, max_seq=self.max_seq,
+                                  last_pos=last_pos)
+
     def _decode_impl(self, params, cache, tok, pos, out_buf, gen_idx):
         """One step for the WHOLE padded batch.  Inactive rows are stepped
         too (their writes land past their read window or are dropped at
@@ -150,6 +150,10 @@ class ServeRuntime:
         out_buf = out_buf.at[jnp.arange(C), gen_idx].set(ntok, mode="drop")
         gen_idx = jnp.where(gen_idx < gen_cap, gen_idx + 1, gen_idx)
         return ntok, pos + 1, out_buf, gen_idx, cache
+
+    @staticmethod
+    def _park_impl(park, out, slot, idx):
+        return park.at[idx].set(out[slot])
 
     def _admit_impl(self, logits, tok, pos, out_buf, gen_idx, slot, p0):
         """Write one admitted row's metadata: first sampled token (argmax

@@ -406,8 +406,14 @@ class Transformer:
             x = policy.acts(x, "embeds_dec")
 
         if cfg.layer_pattern == "rwkv":
-            def body(x, xs):
-                lp, c = xs
+            # The pool rides in the carry and layer l's rows are updated
+            # in place: scanned as xs/ys it would be stacked into a second
+            # pool and copied back whole every step.
+            def body(carry, xs):
+                x, pool = carry
+                l, lp = xs
+                c = {k: jax.lax.dynamic_index_in_dim(v, l, 0, keepdims=False)
+                     for k, v in pool.items()}
                 xn = rms_norm(x, lp["ln1"], cfg.norm_eps)
                 o, (tm_x, state) = rwkv6_time_mix(
                     lp["rwkv"]["tm"], xn, cfg,
@@ -416,10 +422,14 @@ class Transformer:
                 hn = rms_norm(h, lp["ln2"], cfg.norm_eps)
                 o2, cm_x = rwkv6_channel_mix(lp["rwkv"]["cm"], hn, cfg,
                                              x_prev=c["cm_x"])
-                return h + o2, {"tm_x": tm_x.astype(c["tm_x"].dtype),
-                                "cm_x": cm_x.astype(c["cm_x"].dtype),
-                                "state": state}
-            x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+                new = {"tm_x": tm_x, "cm_x": cm_x, "state": state}
+                pool = {k: jax.lax.dynamic_update_index_in_dim(
+                            v, new[k].astype(v.dtype), l, 0)
+                        for k, v in pool.items()}
+                return (h + o2, pool), None
+            n_layers = cache["state"].shape[0]
+            (x, new_cache), _ = jax.lax.scan(
+                body, (x, cache), (jnp.arange(n_layers), params["layers"]))
 
         elif cfg.layer_pattern == "griffin":
             def rec_step(lp, x, c):

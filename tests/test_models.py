@@ -80,6 +80,30 @@ def test_decode_matches_prefill(name):
                                atol=2e-3 * np.abs(b).max())
 
 
+def test_rwkv_donated_decode_steps_match_prefill():
+    """Eight donated decode steps in a row, teacher-forced, after
+    prefill(S): step i's logits == last logits of prefill(S + i + 1).  The
+    state pool is carried and updated in place across steps, not only
+    within one."""
+    cfg = reduced(get_config("rwkv6-3b"))
+    m = Transformer(cfg)
+    params = m.init(jax.random.key(1))
+    B, S, n = 2, 24, 8
+    toks = jnp.asarray(RNG.integers(0, cfg.vocab, (B, S + n)), jnp.int32)
+    _, cache = m.prefill(params, {"tokens": toks[:, :S]}, max_seq=S + n)
+    decode = jax.jit(m.decode_step, donate_argnums=(1,))
+    for i in range(n):
+        ld, cache = decode(params, cache, {"tokens": toks[:, S + i]},
+                           jnp.full((B,), S + i, jnp.int32))
+        lf, _ = m.prefill(params, {"tokens": toks[:, :S + i + 1]},
+                          max_seq=S + n)
+        a = np.asarray(ld, np.float32)
+        b = np.asarray(lf, np.float32)
+        np.testing.assert_allclose(a, b, rtol=2e-3,
+                                   atol=2e-3 * np.abs(b).max(),
+                                   err_msg=f"decode step {i}")
+
+
 def test_moe_decode_matches_prefill_no_dropping():
     """MoE consistency holds exactly when capacity never drops (the
     residual mismatch under dropping is the documented GShard behavior)."""

@@ -5,18 +5,23 @@ described, not attached, so these tests need no chip.  Operands are at the
 widths of the models that use each kernel: flash_attention at
 qwen2.5-14b's layout (40 q heads over 8 kv heads of 128, S = T = 2048,
 bf16), wkv6 at rwkv6-3b's (40 heads of 64, T = 2048), rglru_scan at
-recurrentgemma-2b's width (2560) and rmsnorm at d = 5120.  The topology
-is described inside a fixture, so only the worker that runs this file
-loads the TPU library; all of these tests live in this one file.
+recurrentgemma-2b's width (2560) and rmsnorm at d = 5120.  The decode
+step of rwkv6-3b is compiled whole, at capacity 128, to check that it
+updates its state pool in place.  The topology is described inside a
+fixture, so only the worker that runs this file loads the TPU library;
+all of these tests live in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels import ops, variants
+from repro.models import Transformer
 
 _FLASH = ((1, 2048, 8, 5, 128), (1, 2048, 8, 128), (1, 2048, 8, 128))
 _WKV6 = ((1, 2048, 40, 64),) * 4 + ((40, 64),)
@@ -90,3 +95,39 @@ def test_kernel_tile_compiles_for_v5e(kernel, shapes, variant, one_chip,
     fn = _CALLS[kernel](dict(variant.kwargs(), interpret=False))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+_POOL = "f32[32,128,40,64,64]"       # rwkv6-3b's state pool at capacity 128
+_POOL_LAYER_BYTES = 128 * 40 * 64 * 64 * 4
+
+
+def test_rwkv6_decode_updates_state_pool_in_place(one_chip,
+                                                  no_compile_cache):
+    """The decode step of rwkv6-3b at its published widths, capacity 128,
+    with the cache donated: each layer's rows of the pool are updated in
+    place, so the program needs no second pool (its temporaries stay under
+    one layer's state) and copies no whole pool."""
+    m = Transformer(get_config("rwkv6-3b"))
+    C = 128
+
+    def on_chip(t):
+        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(m.init, jax.random.key(0)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: m.init_cache(C, 1)))
+    assert cache["state"].shape == (32, C, 40, 64, 64)
+    tok = {"tokens": jax.ShapeDtypeStruct((C,), jnp.int32, sharding=one_chip)}
+    pos = jax.ShapeDtypeStruct((C,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(m.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tok, pos).compile()
+
+    assert compiled.memory_analysis().temp_size_in_bytes < _POOL_LAYER_BYTES
+    pool_copies = []
+    for line in compiled.as_text().splitlines():
+        if " = " not in line:
+            continue
+        rhs = line.split(" = ", 1)[1]
+        op = re.search(r"\s(copy(?:-start)?)\(", rhs)
+        if op and _POOL in rhs[:op.start()]:
+            pool_copies.append(line.strip())
+    assert not pool_copies, pool_copies

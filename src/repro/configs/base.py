@@ -29,22 +29,34 @@ class ArchConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0          # routed experts (the router's width)
     top_k: int = 0
     moe_dense_residual: bool = False
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    router: str = "softmax"     # softmax | sigmoid (+ correction bias)
+    routed_scaling: float = 1.0  # sigmoid router: scale of the top-k weights
+    moe_shared_ff: int = 0      # >0: one shared expert of this width
+    experts_held: int = 0       # >0: this chip holds experts
+    expert_offset: int = 0      #     [offset, offset + experts_held)
     # --- hybrid / ssm ---
-    layer_pattern: str = "full"   # full | griffin (R,R,A) | rwkv
+    layer_pattern: str = "full"   # full | griffin (R,R,A) | rwkv | nemotron_h
+    block_pattern: str = ""       # nemotron_h: M (Mamba-2), E (MoE), * (attn)
     local_window: int = 0         # >0: sliding-window attention
     rglru_conv_width: int = 4
     rwkv_head_size: int = 64
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    mamba_conv: int = 4
     # --- io / heads ---
     n_codebooks: int = 0          # musicgen: 4 parallel output heads
     input_embeds: bool = False    # frontend STUB supplies (B, S, d) embeds
     # --- numerics ---
     dtype: str = "bfloat16"
     rope_theta: float = 10_000.0
+    rope: bool = True             # False: attention without rotary embedding
     norm_eps: float = 1e-6
     # --- capability flags ---
     sub_quadratic: bool = False   # True => long_500k is runnable
@@ -58,8 +70,20 @@ class ArchConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def n_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
     def layer_kind(self, i: int) -> str:
-        """'attn' | 'rglru' | 'rwkv' for layer i (griffin: R,R,A pattern)."""
+        """'attn' | 'rglru' | 'rwkv' | 'mamba2' | 'moe' for layer i
+        (griffin: R,R,A pattern; nemotron_h: ``block_pattern``)."""
+        if self.layer_pattern == "nemotron_h":
+            return _NEMOTRON_KINDS[self.block_pattern[i]]
         if self.layer_pattern == "griffin":
             return "attn" if i % 3 == 2 else "rglru"
         if self.layer_pattern == "rwkv":
@@ -68,6 +92,9 @@ class ArchConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         return tuple(self.layer_kind(i) for i in range(self.n_layers))
+
+
+_NEMOTRON_KINDS = {"M": "mamba2", "E": "moe", "*": "attn"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,6 +145,13 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
     cover a full hybrid pattern), tiny width/vocab, few experts."""
     n_layers = 3 if cfg.layer_pattern == "griffin" else 2
     n_heads = 0 if cfg.attn_free else 4
+    if cfg.layer_pattern == "nemotron_h":
+        # every block kind once, Mamba and MoE twice
+        return dataclasses.replace(
+            cfg, name=cfg.name + "-smoke", n_layers=5, block_pattern="MEM*E",
+            d_model=64, n_heads=4, n_kv_heads=2, d_head=16, d_ff=32,
+            vocab=257, n_experts=8, top_k=2, moe_shared_ff=48, mamba_heads=8, mamba_head_dim=8, ssm_state=16,
+            ssm_groups=2, dtype="float32")
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",
@@ -144,6 +178,9 @@ def param_count(cfg: ArchConfig) -> int:
         total += cfg.n_codebooks * v * d    # per-codebook output heads
     else:
         total += v * d                      # untied LM head
+    if cfg.layer_pattern == "nemotron_h":
+        return total + d + sum(_nemotron_block_params(cfg, k)   # + final norm
+                               for k in cfg.layer_kinds())
     for i in range(cfg.n_layers):
         kind = cfg.layer_kind(i)
         total += 2 * d                      # 2 norms
@@ -178,10 +215,31 @@ def param_count(cfg: ArchConfig) -> int:
     return total
 
 
+def _nemotron_block_params(cfg: ArchConfig, kind: str) -> int:
+    """One nemotron_h block: its pre-norm and its one mixer (the experts
+    this chip holds)."""
+    d = cfg.d_model
+    if kind == "mamba2":
+        di, H = cfg.mamba_inner, cfg.mamba_heads
+        conv = di + 2 * cfg.ssm_groups * cfg.ssm_state
+        return (d + d * (di + conv + H) + cfg.mamba_conv * conv + conv
+                + 3 * H + di + di * d)
+    if kind == "moe":
+        return (d + d * cfg.n_experts + cfg.n_experts
+                + 2 * d * (cfg.n_held * cfg.d_ff + cfg.moe_shared_ff))
+    q, kv = cfg.n_heads * cfg.d_head, cfg.n_kv_heads * cfg.d_head
+    return d + 2 * d * q + 2 * d * kv
+
+
 def active_param_count(cfg: ArchConfig) -> int:
     """Active params per token (MoE: top_k of n_experts) for 6·N_active·D."""
     if not cfg.is_moe:
         return param_count(cfg)
+    if cfg.layer_pattern == "nemotron_h":
+        n_moe = cfg.layer_kinds().count("moe")
+        idle = cfg.n_held - cfg.top_k * cfg.n_held / cfg.n_experts
+        return param_count(cfg) - int(n_moe * idle * 2 * cfg.d_model
+                                      * cfg.d_ff)
     d, f = cfg.d_model, cfg.d_ff
     e_params = (3 if cfg.activation in ("swiglu", "geglu") else 2) * d * f
     return param_count(cfg) - cfg.n_layers * \

@@ -15,8 +15,8 @@ import jax.numpy as jnp
 
 from .layers import P, Policy, apply_rope, rms_norm
 
-__all__ = ["attn_spec", "attn_apply", "attn_decode", "init_kv_cache",
-           "blockwise_attention", "decode_attention"]
+__all__ = ["attn_spec", "attn_apply", "attn_decode", "attn_decode_pooled",
+           "init_kv_cache", "blockwise_attention", "decode_attention"]
 
 NEG_INF = -1e30
 
@@ -64,8 +64,9 @@ def _project_qkv(params, x, cfg, positions, policy=None):
     if "qnorm" in params:
         q = rms_norm(q, params["qnorm"])
         k = rms_norm(k, params["knorm"])
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -249,3 +250,24 @@ def attn_decode(params, x, cfg, cache, pos, *,
     out = jnp.einsum("bshk,hkd->bsd", o, params["w_o"])
     new_cache.update({"k": new_k, "v": new_v, "pos": new_cpos})
     return out, new_cache
+
+
+def attn_decode_pooled(params, x, cfg, pool, layer: int, pos):
+    """One decode step of attention layer ``layer`` against the pooled
+    full cache {k, v} of every attention layer, (layers, B, T, K, D), in
+    which slot t holds position t.  The new key and value are scattered
+    into the pool at (layer, row, pos), so a donated pool is updated in
+    place and no layer-sized copy is made; positions up to ``pos`` are
+    valid.  Returns (out (B, 1, d), new pool)."""
+    B = x.shape[0]
+    K, G = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    q, k, v = _project_qkv(params, x, cfg, pos[:, None])
+    b_idx = jnp.arange(B)
+    pool = {"k": pool["k"].at[layer, b_idx, pos].set(k[:, 0]),
+            "v": pool["v"].at[layer, b_idx, pos].set(v[:, 0])}
+    T = pool["k"].shape[2]
+    q = q.reshape(B, 1, K, G, cfg.d_head)
+    o = decode_attention(q, pool["k"][layer], pool["v"][layer],
+                         jnp.broadcast_to(jnp.arange(T), (B, T)), pos)
+    o = o.reshape(B, 1, cfg.n_heads, cfg.d_head)
+    return jnp.einsum("bshk,hkd->bsd", o, params["w_o"]), pool

@@ -1,6 +1,10 @@
 """Mixture-of-Experts FFN: top-k routing, sort-based capacity dispatch,
 expert-parallel grouped compute, optional dense-residual branch (Arctic).
 
+``moe_held_apply`` is the other dispatch, for a chip that holds a share of
+the experts (Nemotron-H): a sigmoid router with a correction bias over all
+experts, and a dropless dispatch to the held ones plus a shared expert.
+
 Dispatch is the static-shape "dropping" formulation (GShard/Switch style,
 sort-based like MaxText): tokens are sorted by assigned expert, ranked
 within the expert, and tokens beyond ``capacity`` are dropped (their combine
@@ -18,11 +22,23 @@ import jax.numpy as jnp
 
 from .layers import P, Policy, ffn_apply, ffn_spec
 
-__all__ = ["moe_spec", "moe_apply", "moe_apply_ep"]
+__all__ = ["moe_spec", "moe_apply", "moe_apply_ep", "moe_held_apply",
+           "sigmoid_route"]
 
 
 def moe_spec(cfg, prefix_shape=(), prefix_names=()) -> Dict[str, Any]:
     pa, pn = tuple(prefix_shape), tuple(prefix_names)
+    if cfg.router == "sigmoid":
+        return {
+            "router": P(pa + (cfg.d_model, cfg.n_experts),
+                        pn + ("embed", "experts")),
+            "router_bias": P(pa + (cfg.n_experts,), pn + ("experts",),
+                             init="zeros"),
+            "experts": ffn_spec(cfg.d_model, cfg.d_ff, cfg.activation,
+                                pa + (cfg.n_held,), pn + ("experts",)),
+            "shared": ffn_spec(cfg.d_model, cfg.moe_shared_ff,
+                               cfg.activation, pa, pn),
+        }
     spec: Dict[str, Any] = {
         "router": P(pa + (cfg.d_model, cfg.n_experts),
                     pn + ("embed", "experts")),
@@ -112,6 +128,48 @@ def moe_apply(params, x, cfg, *, policy: Optional[Policy] = None
         out = out + ffn_apply(params["dense"], xf, cfg.activation,
                               policy=policy)
     return out.reshape(B, S, d), aux
+
+
+def sigmoid_route(params, xf, cfg):
+    """Top-k experts of each token and their weights, in float32: scores
+    ``s = sigmoid(x W_r)``, the k chosen on ``s + bias``, their weights
+    ``s`` at those experts over their sum, times ``routed_scaling``."""
+    s = jax.nn.sigmoid(xf.astype(jnp.float32)
+                       @ params["router"].astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + params["router_bias"].astype(jnp.float32),
+                           cfg.top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+    return w, idx
+
+
+def moe_held_apply(params, x, cfg) -> Tuple[jax.Array, jax.Array]:
+    """The share of an expert layer that the experts ``[expert_offset,
+    expert_offset + n_held)`` give, plus the shared expert, all relu² and
+    not gated, as in Nemotron-H.  x: (B, S, d).
+
+    Each token is routed over all ``n_experts``; a chosen expert that this
+    chip does not hold adds nothing here.  Dropless: a held expert has one
+    slot per token of the call, and a token takes the slot of its own
+    index (a token chooses an expert at most once).  Returns (out, 0)."""
+    B, S, d = x.shape
+    T, Eh, k = B * S, cfg.n_held, cfg.top_k
+    xf = x.reshape(T, d)
+    w, idx = sigmoid_route(params, xf, cfg)                   # (T, k)
+    local = idx - cfg.expert_offset
+    held = (local >= 0) & (local < Eh)
+    tok = jnp.broadcast_to(jnp.arange(T)[:, None], (T, k))
+    slot = jnp.where(held, local * T + tok, Eh * T)           # OOB: dropped
+    buf = jnp.zeros((Eh * T, d), x.dtype).at[slot.reshape(-1)].set(
+        jnp.repeat(xf, k, axis=0), mode="drop").reshape(Eh, T, d)
+    ew = params["experts"]
+    h = jnp.square(jax.nn.relu(jnp.einsum("etd,edf->etf", buf, ew["w_up"])))
+    y = jnp.einsum("etf,efd->etd", h, ew["w_down"]).reshape(Eh * T, d)
+    got = y[jnp.where(held, slot, 0)].astype(jnp.float32)     # (T, k, d)
+    out = jnp.sum(got * jnp.where(held, w, 0.0)[..., None], axis=1)
+    out = out.astype(x.dtype) + ffn_apply(params["shared"], xf,
+                                          cfg.activation)
+    return out.reshape(B, S, d), jnp.zeros((), jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,10 @@ body regardless of depth → small HLO, fast multi-pod compiles) with
 per-layer ``jax.checkpoint`` remat.  The Griffin hybrid (R,R,A pattern)
 scans over *periods* — a period body applies two RG-LRU layers and one
 local-attention layer from separate stacked trees, so no parameter padding
-is wasted (26 layers = 8 periods + 2 tail recurrent layers).
+is wasted (26 layers = 8 periods + 2 tail recurrent layers).  The
+Nemotron-H hybrid follows its irregular block pattern (Mamba-2, MoE,
+attention; one mixer per block) with a Python loop over per-block
+parameters, each mixer under a ``jax.named_scope`` of its kind.
 
 Three entry points:
   * ``loss``        — training objective (chunked CE; never materializes
@@ -22,10 +25,13 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from .attention import attn_apply, attn_decode, attn_spec, init_kv_cache
+from .attention import (attn_apply, attn_decode, attn_decode_pooled,
+                        attn_spec, init_kv_cache)
 from .layers import (P, Policy, abstract_tree, axes_tree, cross_entropy,
                      ffn_apply, ffn_spec, init_tree, rms_norm)
-from .moe import moe_apply, moe_spec
+from .mamba2 import (init_mamba2_cache, mamba2_apply, mamba2_spec,
+                     mamba2_step)
+from .moe import moe_apply, moe_held_apply, moe_spec
 from .rglru import init_rglru_cache, rglru_decode, rglru_spec
 from .rwkv6 import (init_rwkv_cache, rwkv6_channel_mix, rwkv6_spec,
                     rwkv6_time_mix)
@@ -71,6 +77,12 @@ def _rwkv_layer_spec(cfg, n: int) -> Dict[str, Any]:
     }
 
 
+def _nemotron_blocks_spec(cfg):
+    mixers = {"mamba2": mamba2_spec, "moe": moe_spec, "attn": attn_spec}
+    return [{"norm": P((cfg.d_model,), ("embed",), init="ones"),
+             kind: mixers[kind](cfg)} for kind in cfg.layer_kinds()]
+
+
 def model_spec(cfg) -> Dict[str, Any]:
     d, v = cfg.d_model, cfg.vocab
     spec: Dict[str, Any] = {
@@ -85,6 +97,8 @@ def model_spec(cfg) -> Dict[str, Any]:
 
     if cfg.layer_pattern == "rwkv":
         spec["layers"] = _rwkv_layer_spec(cfg, cfg.n_layers)
+    elif cfg.layer_pattern == "nemotron_h":
+        spec["blocks"] = _nemotron_blocks_spec(cfg)
     elif cfg.layer_pattern == "griffin":
         n_periods, tail = divmod(cfg.n_layers, 3)
         spec["periods"] = {
@@ -132,22 +146,31 @@ def _full_cache_from_kv(k, v, max_seq: int):
     return {"k": ck, "v": cv, "pos": cpos}
 
 
+def _attn_with_cache(p, xn, cfg, positions, window, max_seq):
+    """Causal self-attention over the prompt and the cache it leaves: a
+    ring buffer of ``window`` positions, or the full cache of
+    ``max_seq``."""
+    from .attention import _project_qkv, blockwise_attention
+    B, S, _ = xn.shape
+    K, G = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    q, k, v = _project_qkv(p, xn, cfg, positions)
+    qr = q.reshape(B, S, K, G, cfg.d_head)
+    o = blockwise_attention(qr, k, v, causal=True, window=window)
+    o = o.reshape(B, S, cfg.n_heads, cfg.d_head)
+    attn_out = jnp.einsum("bshk,hkd->bsd", o, p["w_o"])
+    cache = (_ring_cache_from_kv(k, v, window) if window
+             else _full_cache_from_kv(k, v, max_seq))
+    return attn_out, cache
+
+
 def _attn_block(lp, x, cfg, positions, policy, window, use_pallas,
                 collect=False, max_seq=0, moe_ep=False):
     xn = rms_norm(x, lp["ln1"], cfg.norm_eps)
     if policy is not None:
         xn = policy.acts(xn, "block_in")
     if collect:
-        from .attention import _project_qkv, blockwise_attention
-        B, S, _ = xn.shape
-        K, G = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-        q, k, v = _project_qkv(lp["attn"], xn, cfg, positions)
-        qr = q.reshape(B, S, K, G, cfg.d_head)
-        o = blockwise_attention(qr, k, v, causal=True, window=window)
-        o = o.reshape(B, S, cfg.n_heads, cfg.d_head)
-        attn_out = jnp.einsum("bshk,hkd->bsd", o, lp["attn"]["w_o"])
-        cache = (_ring_cache_from_kv(k, v, window) if window
-                 else _full_cache_from_kv(k, v, max_seq))
+        attn_out, cache = _attn_with_cache(lp["attn"], xn, cfg, positions,
+                                           window, max_seq)
     else:
         attn_out = attn_apply(lp["attn"], xn, cfg, positions, policy=policy,
                               window=window, use_pallas=use_pallas)
@@ -211,6 +234,65 @@ def _rwkv_block(lp, x, cfg, policy, use_pallas, collect=False):
     return out, cache
 
 
+def _nemotron_backbone(params, x, cfg, positions, collect, max_seq):
+    """Every block in pattern order; the caches stacked per kind:
+    {ssm, conv} of the Mamba-2 blocks and {kv: k, v} of the attention
+    blocks."""
+    got: Dict[str, list] = {"mamba2": [], "attn": []}
+    for kind, lp in zip(cfg.layer_kinds(), params["blocks"]):
+        with jax.named_scope(kind):
+            xn = rms_norm(x, lp["norm"], cfg.norm_eps)
+            if kind == "mamba2":
+                o, c = mamba2_apply(lp["mamba2"], xn, cfg, collect=collect)
+            elif kind == "moe":
+                o, c = moe_held_apply(lp["moe"], xn, cfg)[0], {}
+            elif collect:
+                # slot t of the full cache holds position t: no "pos" leaf
+                o, c = _attn_with_cache(lp["attn"], xn, cfg, positions, 0,
+                                        max_seq)
+                c = {"k": c["k"], "v": c["v"]}
+            else:
+                o, c = attn_apply(lp["attn"], xn, cfg, positions), {}
+        x = x + o
+        if c:
+            got[kind].append(c)
+    if not collect:
+        return x, {}
+
+    def stack(cs):
+        return jax.tree.map(lambda *t: jnp.stack(t), *cs)
+    return x, dict(stack(got["mamba2"]), kv=stack(got["attn"]))
+
+
+def _nemotron_decode(params, x, cfg, pool, pos):
+    """One token through every block.  The pooled cache is threaded
+    through the blocks and each block's rows are written back in place:
+    the Mamba-2 state and conv window of block i with
+    ``dynamic_update_index_in_dim``, the new key and value by a scatter
+    into the KV pool."""
+    n = {"mamba2": 0, "attn": 0}
+    for kind, lp in zip(cfg.layer_kinds(), params["blocks"]):
+        i = n.get(kind, 0)
+        with jax.named_scope(kind):
+            xn = rms_norm(x, lp["norm"], cfg.norm_eps)
+            if kind == "mamba2":
+                o, ssm, win = mamba2_step(lp["mamba2"], xn, cfg,
+                                          pool["ssm"][i], pool["conv"][i])
+                pool = dict(pool, ssm=jax.lax.dynamic_update_index_in_dim(
+                    pool["ssm"], ssm, i, 0),
+                    conv=jax.lax.dynamic_update_index_in_dim(
+                        pool["conv"], win.astype(pool["conv"].dtype), i, 0))
+            elif kind == "moe":
+                o, _ = moe_held_apply(lp["moe"], xn, cfg)
+            else:
+                o, kv = attn_decode_pooled(lp["attn"], xn, cfg, pool["kv"],
+                                           i, pos)
+                pool = dict(pool, kv=kv)
+        n[kind] = i + 1
+        x = x + o
+    return x, pool
+
+
 # ---------------------------------------------------------------------------
 # The model
 # ---------------------------------------------------------------------------
@@ -254,6 +336,11 @@ class Transformer:
         """Run all layers.  Returns (hidden, aux_loss, caches)."""
         cfg = self.cfg
         use_pallas = self.use_pallas
+
+        if cfg.layer_pattern == "nemotron_h":
+            x, caches = _nemotron_backbone(params, x, cfg, positions,
+                                           collect, max_seq)
+            return x, 0.0, caches
 
         if cfg.layer_pattern == "rwkv":
             def body(carry, lp):
@@ -375,6 +462,12 @@ class Transformer:
         dt = dtype or jnp.dtype(cfg.dtype)
         if cfg.layer_pattern == "rwkv":
             return init_rwkv_cache(cfg, cfg.n_layers, batch, dt)
+        if cfg.layer_pattern == "nemotron_h":
+            kinds = cfg.layer_kinds()
+            cache = init_mamba2_cache(cfg, kinds.count("mamba2"), batch, dt)
+            kv = init_kv_cache(cfg, batch, max_seq, kinds.count("attn"), dt)
+            cache["kv"] = {"k": kv["k"], "v": kv["v"]}
+            return cache
         if cfg.layer_pattern == "griffin":
             n_periods, tail = divmod(cfg.n_layers, 3)
             rec = init_rglru_cache(cfg, n_periods * 2, batch, dt)
@@ -405,7 +498,10 @@ class Transformer:
         if policy is not None:
             x = policy.acts(x, "embeds_dec")
 
-        if cfg.layer_pattern == "rwkv":
+        if cfg.layer_pattern == "nemotron_h":
+            x, new_cache = _nemotron_decode(params, x, cfg, cache, pos)
+
+        elif cfg.layer_pattern == "rwkv":
             # The pool rides in the carry and layer l's rows are updated
             # in place: scanned as xs/ys it would be stacked into a second
             # pool and copied back whole every step.

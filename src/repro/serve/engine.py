@@ -84,7 +84,8 @@ class ServeRuntime:
         # two logical streams: 0 = decode compute, 1 = prefill + fetches
         self.be = be.variant(n_streams=max(be.n_streams, 2))
         self.model = Transformer(cfg, use_pallas=use_pallas)
-        self.exact_buckets = cfg.layer_pattern in ("rwkv", "griffin")
+        self.exact_buckets = cfg.layer_pattern in ("rwkv", "griffin",
+                                                  "nemotron_h")
 
         # weights resident once, through the instrumented residency layer
         owned = params is None

@@ -67,6 +67,14 @@ def cache_bytes_per_slot(model, max_seq: int) -> int:
                for x in jax.tree.leaves(shapes))
 
 
+def _slot_bytes(cache, capacity: int) -> dict:
+    """Bytes of one slot under each top-level key of the pooled tree
+    (e.g. a recurrent state, a conv window, a KV cache)."""
+    import jax
+    return {k: sum(x.nbytes for x in jax.tree.leaves(v)) // capacity
+            for k, v in cache.items()}
+
+
 class KVSlotPool:
     """Slot allocator + owner of the pooled cache tree.
 
@@ -85,6 +93,7 @@ class KVSlotPool:
         self.max_seq = int(max_seq)
         self.batch_axes = infer_batch_axes(model, max_seq)
         self.cache = model.init_cache(capacity, max_seq)
+        self.slot_bytes = _slot_bytes(self.cache, capacity)
         self._free: List[int] = list(range(capacity - 1, -1, -1))
         self._in_use: set = set()
         self.allocs = 0
@@ -137,6 +146,7 @@ class KVSlotPool:
             "allocs": self.allocs,
             "frees": self.frees,
             "reused_slots": self.reused_slots,
+            "slot_bytes": dict(self.slot_bytes),
         }
 
     # -- pooled-cache insert -------------------------------------------------

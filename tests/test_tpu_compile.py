@@ -6,11 +6,13 @@ widths of the models that use each kernel: flash_attention at
 qwen2.5-14b's layout (40 q heads over 8 kv heads of 128, S = T = 2048,
 bf16), wkv6 at rwkv6-3b's (40 heads of 64, T = 2048), rglru_scan at
 recurrentgemma-2b's width (2560) and rmsnorm at d = 5120.  The decode
-step of rwkv6-3b is compiled whole, at capacity 128, to check that it
-updates its state pool in place.  The topology is described inside a
+steps of rwkv6-3b (capacity 128) and of nemotron3-nano-30b-a3b (capacity
+64, 8 experts held, 1536 positions) are compiled whole, to check that
+they update their pools in place.  The topology is described inside a
 fixture, so only the worker that runs this file loads the TPU library;
 all of these tests live in this one file.
 """
+import dataclasses
 import os
 import re
 
@@ -131,3 +133,64 @@ def test_rwkv6_decode_updates_state_pool_in_place(one_chip,
         if op and _POOL in rhs[:op.start()]:
             pool_copies.append(line.strip())
     assert not pool_copies, pool_copies
+
+
+def _materialized_copies(hlo_text, shapes):
+    """Copies whose result lands in memory (in the entry computation, or
+    as the root of a fusion) with one of ``shapes``."""
+    out, entry = [], False
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY"):
+            entry = True
+        elif not line.strip():
+            entry = False
+        if " = " not in line:
+            continue
+        rhs = line.split(" = ", 1)[1]
+        op = re.search(r"\s(copy(?:-start)?)\(", rhs)
+        if op and (entry or line.lstrip().startswith("ROOT")) and any(
+                s in rhs[:op.start()] for s in shapes):
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_nemotron_h_decode_fits_and_updates_pool_in_place(one_chip,
+                                                         no_compile_cache):
+    """The decode step of nemotron3-nano-30b-a3b at its published widths,
+    holding 8 of the 128 experts, at the benchmark cell's capacity 64 and
+    1536 positions, cache donated: the weights (8.08 GB) and the pool fit
+    the chip's 16 GB, the program needs no second pool (temporaries under
+    one block's SSM state), and no state- or KV-shaped copy is
+    materialized, of the whole pool or of one block's rows."""
+    cfg = dataclasses.replace(get_config("nemotron3-nano-30b-a3b"),
+                              experts_held=8)
+    m = Transformer(cfg)
+    C, T = 64, 1536
+
+    def on_chip(t):
+        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(m.init, jax.random.key(0)))
+    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: m.init_cache(C, T)))
+    weight_bytes = sum(t.size * t.dtype.itemsize
+                       for t in jax.tree.leaves(params))
+    assert 8.0e9 < weight_bytes < 8.1e9
+    assert cache["ssm"].shape == (23, C, 64, 64, 128)
+    assert cache["kv"]["k"].shape == (6, C, T, 2, 128)
+    tok = {"tokens": jax.ShapeDtypeStruct((C,), jnp.int32, sharding=one_chip)}
+    pos = jax.ShapeDtypeStruct((C,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(m.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tok, pos).compile()
+
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert mem.temp_size_in_bytes < C * 64 * 64 * 128 * 4
+    text = compiled.as_text()
+    for pool in ("f32[23,64,64,64,128]", "bf16[6,64,1536,2,128]",
+                 "bf16[23,64,3,6144]"):
+        assert not [ln for ln in text.splitlines()
+                    if pool + "{" in ln and re.search(r"\scopy(-start)?\(",
+                                                      ln)], pool
+    rows = ("f32[64,64,64,128]", "f32[1,64,64,64,128]",
+            "bf16[64,1536,2,128]", "bf16[1,64,1536,2,128]")
+    assert not _materialized_copies(text, rows)

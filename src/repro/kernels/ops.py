@@ -17,8 +17,10 @@ from . import flash_attention as _fa
 from . import rglru_scan as _rg
 from . import rmsnorm as _rn
 from . import wkv6 as _wkv
+from . import wkv6_decode as _wkd
 
-__all__ = ["flash_attention", "rglru_scan", "wkv6", "rmsnorm"]
+__all__ = ["flash_attention", "rglru_scan", "wkv6", "wkv6_decode",
+           "rmsnorm"]
 
 
 @functools.partial(jax.custom_vjp,
@@ -89,6 +91,27 @@ def wkv6(r, k, v, w, u, *, block_t: int = 64, interpret: bool = False):
                             block_t=block_t, interpret=interpret)
     o = o.reshape(B, H, T, hs).transpose(0, 2, 1, 3)
     return o, s.reshape(B, H, hs, hs)
+
+
+@functools.partial(jax.jit, static_argnames=("block_b", "block_h",
+                                             "interpret"))
+def wkv6_decode(state, layer, r, k, v, w, u, *, block_b: int = 2,
+                block_h: int = 40, interpret: bool = False):
+    """One token against layer ``layer`` of the pooled state (L, B, H, R,
+    N) fp32, updated in place; r, k, v, w: (B, H, hs); u: (H, hs).
+    Returns (o (B, H, hs) fp32, state), as ``ref.wkv6_decode_ref``."""
+    B, H, hs = r.shape
+    N = state.shape[-1]
+    # the largest tiles up to the asked ones that divide the rows and heads
+    bb = max(d for d in range(1, min(block_b, B) + 1) if B % d == 0)
+    bh = max(d for d in range(1, min(block_h, H) + 1) if H % d == 0)
+    y, state = _wkd.wkv6_decode_pooled(state, layer, r, k, v, w,
+                                       block_b=bb, block_h=bh,
+                                       interpret=interpret)
+    r, k, v, u = (t.astype(jnp.float32) for t in (r, k, v, u))
+    o = (y.reshape(B, H, N // hs, hs).sum(2)
+         + v * jnp.sum(r * u * k, axis=-1, keepdims=True))
+    return o, state
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["flash_attention_ref", "rglru_scan_ref", "wkv6_ref",
-           "rmsnorm_ref"]
+           "wkv6_decode_ref", "rmsnorm_ref"]
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
@@ -60,6 +60,24 @@ def wkv6_ref(r, k, v, w, u):
     s, o = jax.lax.scan(step, s0, tuple(jnp.moveaxis(t, 1, 0)
                                         for t in (r, k, v, w)))
     return jnp.moveaxis(o, 0, 1), s
+
+
+def wkv6_decode_ref(state, layer, r, k, v, w, u):
+    """One RWKV-6 token against layer ``layer`` of a pooled state
+    (L, B, H, R, N): each head's (hs, hs) state row-major in R rows of N
+    lanes (``models.rwkv6.pack_state``), so the update is written on the
+    unpacked view.  r, k, v, w: (B, H, hs); u: (H, hs).  Returns
+    (o (B, H, hs) fp32, the pool with the layer's rows advanced)."""
+    B, H, hs = r.shape
+    s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    packed = s.shape
+    s = s.reshape(B, H, hs, hs)
+    r, k, v, w, u = (t.astype(jnp.float32) for t in (r, k, v, w, u))
+    kv = k[..., :, None] * v[..., None, :]
+    o = jnp.einsum("bhi,bhij->bhj", r, s + u[..., :, None] * kv)
+    s = w[..., :, None] * s + kv
+    return o, jax.lax.dynamic_update_index_in_dim(
+        state, s.reshape(packed), layer, 0)
 
 
 def rmsnorm_ref(x, w, *, eps: float = 1e-6):

@@ -17,8 +17,9 @@ hooks the kernel axis of the plan-space tuner needs:
   * the operand-shape convention: a kernel-tagged block's declared reads
     are, in order, the kernel's array operands at the *ops layer* layout
     (``flash_attention``: q (B,S,K,G,D), k, v (B,T,K,D); ``wkv6``: r, k, v,
-    w (B,T,H,hs), u (H,hs); ``rglru_scan``: a, b (B,T,D); ``rmsnorm``:
-    x (..., D), w (D,)).
+    w (B,T,H,hs), u (H,hs); ``wkv6_decode``: state (L,B,H,R,N), layer
+    (), r, k, v, w (B,H,hs), u (H,hs); ``rglru_scan``: a, b (B,T,D);
+    ``rmsnorm``: x (..., D), w (D,)).
 
 This module is imported by the tuner/roofline layer and therefore stays
 stdlib-only — no jax, no numpy (``repro.kernels.__init__`` pulls jax, so
@@ -148,6 +149,49 @@ def _wkv6_roofline(shapes, itemsizes, params):
     return flops, float(bytes_)
 
 
+# --- wkv6_decode: state (L, B, H, R, N); layer (); r, k, v, w (B, H, hs);
+#     u (H, hs) -------------------------------------------------------------
+
+def _divisor_at_most(block: int, axis: int) -> int:
+    # mirror ops.wkv6_decode: the largest tile up to block dividing axis
+    return max(d for d in range(1, min(int(block), int(axis)) + 1)
+               if axis % d == 0)
+
+
+def _wkv6_decode_validate(shapes, params) -> Optional[Params]:
+    (L, B, H, R, N) = shapes[0]
+    return {"block_b": _divisor_at_most(params["block_b"], B),
+            "block_h": _divisor_at_most(params["block_h"], H)}
+
+
+def _wkv6_decode_lanes(shapes, params) -> int:
+    """Lanes of one row of the r/k/w tiles: each head's N / hs values,
+    padded to whole 128-lane tiles."""
+    N, hs = shapes[0][-1], shapes[2][-1]
+    return -(-params["block_h"] * (N // hs) // 128) * 128
+
+
+def _wkv6_decode_workset(shapes, itemsizes, params):
+    (L, B, H, R, N) = shapes[0]
+    bb, bh = params["block_b"], params["block_h"]
+    lanes = _wkv6_decode_lanes(shapes, params)
+    # fp32 state tile in and out, r/k/w tiles, v in and y out, each
+    # double-buffered
+    return float(2 * 4 * bb * (2 * bh * R * N + 3 * R * lanes
+                               + 2 * bh * N))
+
+
+def _wkv6_decode_roofline(shapes, itemsizes, params):
+    (L, B, H, R, N) = shapes[0]
+    lanes = _wkv6_decode_lanes(shapes, params)
+    # per state element: r·S (2 flops), w·S + k·v (3)
+    flops = 5.0 * B * H * R * N
+    bytes_ = 4 * (2 * B * H * R * N            # one layer's state, in and out
+                  + 3 * B * (H // params["block_h"]) * R * lanes   # r, k, w
+                  + 2 * B * H * N)             # v in, y out
+    return flops, float(bytes_)
+
+
 # --- rglru_scan: a, b (B, T, D) -------------------------------------------
 
 def _rglru_validate(shapes, params) -> Optional[Params]:
@@ -229,6 +273,17 @@ KERNELS: Dict[str, dict] = {
         "validate": _wkv6_validate,
         "roofline": _wkv6_roofline,
         "workset": _wkv6_workset,
+    },
+    "wkv6_decode": {
+        # a head tile of 8 is absent: at rwkv6-3b's widths its r/k/w
+        # rows fill 16 of 128 lanes, reading 31 MB a layer beside the
+        # state's 168 MB; 8 rows at 40 heads need 21 MB of VMEM, over
+        # v5e's 16 MB scoped limit
+        "grid": {"block_b": (1, 2, 4), "block_h": (20, 40)},
+        "defaults": {"block_b": 2, "block_h": 40},
+        "validate": _wkv6_decode_validate,
+        "roofline": _wkv6_decode_roofline,
+        "workset": _wkv6_decode_workset,
     },
     "rglru_scan": {
         # 256 is absent: at recurrentgemma-2b's width (D = 2560) its fp32

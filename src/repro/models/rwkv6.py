@@ -7,7 +7,9 @@ Per head (head size hs), with state S ∈ R^{hs×hs}:
 where w_t = exp(-exp(w0 + lora_w(x̃_t))) is the data-dependent decay (the
 paper's headline novelty over RWKV-5) and the x̃ inputs are ddlerp token
 shifts.  Training uses a time scan (Pallas chunked kernel on real TPU:
-``repro.kernels.wkv6``); decode carries (S, x_prev) per layer.
+``repro.kernels.wkv6``); decode carries (S, x_prev) per layer, S in the
+serving pool's lane-dense form (``state_shape``), stepped on a TPU by
+``repro.kernels.wkv6_decode``.
 """
 from __future__ import annotations
 
@@ -18,8 +20,9 @@ import jax.numpy as jnp
 
 from .layers import P, Policy, rms_norm
 
-__all__ = ["rwkv6_spec", "rwkv6_time_mix", "rwkv6_channel_mix",
-           "init_rwkv_cache", "wkv6_scan_ref"]
+__all__ = ["rwkv6_spec", "rwkv6_time_mix", "rwkv6_time_mix_step",
+           "rwkv6_channel_mix", "init_rwkv_cache", "state_shape",
+           "pack_state", "unpack_state", "wkv6_scan_ref"]
 
 LORA_R = 32
 _MIX = ("w", "k", "v", "r", "g")
@@ -70,32 +73,16 @@ def wkv6_scan_ref(r, k, v, w, u):
     """Sequential oracle.  r,k,v,w: (B, T, H, hs); u: (H, hs) bonus.
     Returns (o (B,T,H,hs), final state (B,H,hs,hs))."""
     B, T, H, hs = r.shape
-    s0 = jnp.zeros((B, H, hs, hs), jnp.float32)
-
-    def step(s, inp):
-        r_t, k_t, v_t, w_t = inp          # (B, H, hs)
-        kv = k_t[..., :, None] * v_t[..., None, :]      # (B,H,hs,hs)
-        o = jnp.einsum("bhi,bhij->bhj", r_t, s + u[..., :, None] * kv)
-        s = w_t[..., :, None] * s + kv
-        return s, o
-
-    rr, kk, vv, ww = (jnp.moveaxis(t.astype(jnp.float32), 1, 0)
-                      for t in (r, k, v, w))
-    s, o = jax.lax.scan(step, s0, (rr, kk, vv, ww))
-    return jnp.moveaxis(o, 0, 1), s
+    return _wkv_with_state(r, k, v, w, u,
+                           jnp.zeros((B, H, hs, hs), jnp.float32))
 
 
-def rwkv6_time_mix(p, x, cfg, *, x_prev=None, state=None,
-                   policy: Optional[Policy] = None,
-                   use_pallas: bool = False):
-    """x: (B, T, d).  Returns (out, (new_x_prev, new_state))."""
+def _time_mix_inputs(p, x, sx, cfg):
+    """r, k, v, w (B, T, H, hs), the gate g (B, T, d) and the bonus u
+    (H, hs) of the time mix of x against its shifted sx."""
     B, T, d = x.shape
     hs = cfg.rwkv_head_size
     H = d // hs
-    if x_prev is None:
-        x_prev = jnp.zeros((B, d), x.dtype)
-    sx = _token_shift(x, x_prev)
-
     xw = _ddlerp(p, x, sx, "w")
     xk = _ddlerp(p, x, sx, "k")
     xv = _ddlerp(p, x, sx, "v")
@@ -108,28 +95,55 @@ def rwkv6_time_mix(p, x, cfg, *, x_prev=None, state=None,
     g = jax.nn.silu(xg @ p["w_g"])
     dec = p["w0"] + jnp.tanh(xw @ p["lora_a_w"]) @ p["lora_b_w"]
     w = jnp.exp(-jnp.exp(dec.astype(jnp.float32))).reshape(B, T, H, hs)
-    u = p["u"].reshape(H, hs)
+    return r, k, v, w, g, p["u"].reshape(H, hs)
 
-    if state is not None:
-        # decode / segment continuation: fold initial state in via the scan
-        o, new_state = _wkv_with_state(r, k, v, w, u, state)
-    elif use_pallas:
+
+def _time_mix_out(p, o, g, x, policy):
+    """Per-head norm of the wkv output o (B, T, H, hs), gated and
+    projected."""
+    B, T, d = x.shape
+    o = rms_norm(o.astype(x.dtype), jnp.ones((o.shape[-1],), x.dtype)
+                 ).reshape(B, T, d) * p["ln_x"]
+    if policy is not None:
+        o = policy.acts(o, "embeds")
+    return (o * g) @ p["w_out"]
+
+
+def rwkv6_time_mix(p, x, cfg, *, x_prev=None,
+                   policy: Optional[Policy] = None,
+                   use_pallas: bool = False):
+    """x: (B, T, d), from a zero state.  Returns (out, (new_x_prev,
+    final state (B, H, hs, hs)))."""
+    B, T, d = x.shape
+    if x_prev is None:
+        x_prev = jnp.zeros((B, d), x.dtype)
+    r, k, v, w, g, u = _time_mix_inputs(p, x, _token_shift(x, x_prev), cfg)
+    if use_pallas:
         from repro.kernels import ops as kops
         o, new_state = kops.wkv6(r, k, v, w, u)
     else:
         o, new_state = wkv6_scan_ref(r, k, v, w, u)
+    return _time_mix_out(p, o, g, x, policy), (x[:, -1], new_state)
 
-    o = o.reshape(B, T, d).astype(x.dtype)
-    o = rms_norm(o.reshape(B, T, H, hs), jnp.ones((hs,), x.dtype)
-                 ).reshape(B, T, d) * p["ln_x"]
-    if policy is not None:
-        o = policy.acts(o, "embeds")
-    out = (o * g) @ p["w_out"]
-    return out, (x[:, -1], new_state)
+
+def rwkv6_time_mix_step(p, x, cfg, pool, layer, *, x_prev,
+                        policy: Optional[Policy] = None):
+    """One token, x: (B, 1, d), against layer ``layer`` of the pooled
+    state leaf ``pool`` (L, B, H, *state_shape(hs)), which is updated in
+    place.  Returns (out, (x[:, -1], pool))."""
+    from repro.kernels import ops as kops
+    from repro.kernels import ref as kref
+    r, k, v, w, g, u = _time_mix_inputs(p, x, x_prev[:, None], cfg)
+    # the kernel is chosen by the compile target, not by the process's
+    # default backend: a CPU process may compile for a TPU
+    o, pool = jax.lax.platform_dependent(
+        pool, layer, r[:, 0], k[:, 0], v[:, 0], w[:, 0], u,
+        tpu=kops.wkv6_decode, default=kref.wkv6_decode_ref)
+    return _time_mix_out(p, o[:, None], g, x, policy), (x[:, -1], pool)
 
 
 def _wkv_with_state(r, k, v, w, u, s0):
-    B, T, H, hs = r.shape
+    """The recurrence token by token from the state s0 (B, H, hs, hs)."""
 
     def step(s, inp):
         r_t, k_t, v_t, w_t = inp
@@ -157,6 +171,28 @@ def rwkv6_channel_mix(p, x, cfg, *, x_prev=None):
     return jax.nn.sigmoid(xr @ p["w_r"]) * (kk @ p["w_v"]), x[:, -1]
 
 
+def state_shape(hs: int):
+    """How a head's (hs, hs) state is stored in the serving pool.  Where
+    hs divides 128 it is packed row-major into hs·hs/128 rows of 128
+    lanes, so the minor axis fills the TPU's lanes and the pool is laid
+    out batch-major: one request's state is then a contiguous row of the
+    pool, and inserting it writes only that row.  A minor axis of 64
+    would instead put the batch axis in the lanes."""
+    if hs < 128 and 128 % hs == 0:
+        return (hs * hs // 128, 128)
+    return (hs, hs)
+
+
+def pack_state(s):
+    """(..., hs, hs) → (..., *state_shape(hs)): a row-major reshape, so
+    lane n of row p holds S[p·(128/hs) + n // hs, n % hs]."""
+    return s.reshape(s.shape[:-2] + state_shape(s.shape[-1]))
+
+
+def unpack_state(s, hs: int):
+    return s.reshape(s.shape[:-2] + (hs, hs))
+
+
 def init_rwkv_cache(cfg, n_layers: int, batch: int, dtype=jnp.bfloat16):
     d = cfg.d_model
     hs = cfg.rwkv_head_size
@@ -164,7 +200,8 @@ def init_rwkv_cache(cfg, n_layers: int, batch: int, dtype=jnp.bfloat16):
     return {
         "tm_x": jnp.zeros((n_layers, batch, d), dtype),
         "cm_x": jnp.zeros((n_layers, batch, d), dtype),
-        "state": jnp.zeros((n_layers, batch, H, hs, hs), jnp.float32),
+        "state": jnp.zeros((n_layers, batch, H) + state_shape(hs),
+                           jnp.float32),
     }
 
 

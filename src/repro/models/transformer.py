@@ -33,8 +33,8 @@ from .mamba2 import (init_mamba2_cache, mamba2_apply, mamba2_spec,
                      mamba2_step)
 from .moe import moe_apply, moe_held_apply, moe_spec
 from .rglru import init_rglru_cache, rglru_decode, rglru_spec
-from .rwkv6 import (init_rwkv_cache, rwkv6_channel_mix, rwkv6_spec,
-                    rwkv6_time_mix)
+from .rwkv6 import (init_rwkv_cache, pack_state, rwkv6_channel_mix,
+                    rwkv6_spec, rwkv6_time_mix, rwkv6_time_mix_step)
 
 __all__ = ["Transformer", "model_spec"]
 
@@ -229,7 +229,8 @@ def _rwkv_block(lp, x, cfg, policy, use_pallas, collect=False):
     out = h + o2
     if policy is not None:
         out = policy.acts(out, "embeds")
-    cache = ({"tm_x": tm_x, "cm_x": cm_x, "state": state}
+    # the state in the pool's packed form (one relayout per request)
+    cache = ({"tm_x": tm_x, "cm_x": cm_x, "state": pack_state(state)}
              if collect else {})
     return out, cache
 
@@ -508,20 +509,21 @@ class Transformer:
             def body(carry, xs):
                 x, pool = carry
                 l, lp = xs
-                c = {k: jax.lax.dynamic_index_in_dim(v, l, 0, keepdims=False)
-                     for k, v in pool.items()}
+                c = {k: jax.lax.dynamic_index_in_dim(pool[k], l, 0,
+                                                     keepdims=False)
+                     for k in ("tm_x", "cm_x")}
                 xn = rms_norm(x, lp["ln1"], cfg.norm_eps)
-                o, (tm_x, state) = rwkv6_time_mix(
-                    lp["rwkv"]["tm"], xn, cfg,
-                    x_prev=c["tm_x"], state=c["state"], policy=policy)
+                o, (tm_x, state) = rwkv6_time_mix_step(
+                    lp["rwkv"]["tm"], xn, cfg, pool["state"], l,
+                    x_prev=c["tm_x"], policy=policy)
                 h = x + o
                 hn = rms_norm(h, lp["ln2"], cfg.norm_eps)
                 o2, cm_x = rwkv6_channel_mix(lp["rwkv"]["cm"], hn, cfg,
                                              x_prev=c["cm_x"])
-                new = {"tm_x": tm_x, "cm_x": cm_x, "state": state}
+                new = {"tm_x": tm_x, "cm_x": cm_x}
                 pool = {k: jax.lax.dynamic_update_index_in_dim(
-                            v, new[k].astype(v.dtype), l, 0)
-                        for k, v in pool.items()}
+                            pool[k], new[k].astype(pool[k].dtype), l, 0)
+                        for k in new} | {"state": state}
                 return (h + o2, pool), None
             n_layers = cache["state"].shape[0]
             (x, new_cache), _ = jax.lax.scan(

@@ -125,6 +125,8 @@ def test_flash_attention_grad_matches_ref():
 
 _FLASH_SHAPES = ((1, 128, 1, 2, 8), (1, 128, 1, 8), (1, 128, 1, 8))
 _WKV6_SHAPES = ((1, 128, 2, 8),) * 4 + ((2, 8),)
+_WKV6_DECODE_SHAPES = ((2, 4, 40, 32, 128), ()) + ((4, 40, 64),) * 4 + (
+    (40, 64),)
 _RGLRU_SHAPES = ((1, 256, 8),) * 2
 _RMSNORM_SHAPES = ((128, 32), (32,))
 
@@ -133,6 +135,7 @@ def _variant_cases():
     cases = []
     for kernel, shapes in (("flash_attention", _FLASH_SHAPES),
                            ("wkv6", _WKV6_SHAPES),
+                           ("wkv6_decode", _WKV6_DECODE_SHAPES),
                            ("rglru_scan", _RGLRU_SHAPES),
                            ("rmsnorm", _RMSNORM_SHAPES)):
         for v in variants.variants_for(kernel, shapes):
@@ -142,9 +145,10 @@ def _variant_cases():
 
 def test_registry_covers_every_kernel():
     assert set(variants.kernel_names()) == {
-        "flash_attention", "wkv6", "rglru_scan", "rmsnorm"}
+        "flash_attention", "wkv6", "wkv6_decode", "rglru_scan", "rmsnorm"}
     for kernel, shapes in (("flash_attention", _FLASH_SHAPES),
                            ("wkv6", _WKV6_SHAPES),
+                           ("wkv6_decode", _WKV6_DECODE_SHAPES),
                            ("rglru_scan", _RGLRU_SHAPES),
                            ("rmsnorm", _RMSNORM_SHAPES)):
         vs = variants.variants_for(kernel, shapes)
@@ -181,6 +185,17 @@ def test_every_registry_variant_matches_ref(kernel, shapes, variant):
         np.testing.assert_allclose(
             np.asarray(s.reshape(B * H, hs, hs)), np.asarray(s_ref),
             rtol=2e-4, atol=2e-4)
+    elif kernel == "wkv6_decode":
+        state = _rand(shapes[0], jnp.float32)
+        r, k, v = (_rand(sh, jnp.float32) for sh in shapes[2:5])
+        w = jnp.asarray(RNG.uniform(0.2, 0.99, shapes[5]).astype(np.float32))
+        u = _rand(shapes[6], jnp.float32)
+        o, s = ops.wkv6_decode(state, 1, r, k, v, w, u, **kw)
+        o_ref, s_ref = ref.wkv6_decode_ref(state, 1, r, k, v, w, u)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                                   rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
+                                   rtol=1e-6, atol=1e-6)
     elif kernel == "rglru_scan":
         a = jnp.asarray(RNG.uniform(0.4, 0.999, shapes[0])
                         .astype(np.float32))

@@ -1,5 +1,6 @@
 """Per-arch smoke tests (reduced configs) + cache-consistency checks."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -102,6 +103,63 @@ def test_rwkv_donated_decode_steps_match_prefill():
         np.testing.assert_allclose(a, b, rtol=2e-3,
                                    atol=2e-3 * np.abs(b).max(),
                                    err_msg=f"decode step {i}")
+
+
+@pytest.mark.parametrize("hs,H,B", [(16, 4, 2), (64, 3, 3)])
+@pytest.mark.parametrize("form", ["kernel", "jnp"])
+def test_packed_wkv6_decode_matches_unpacked_update(hs, H, B, form):
+    """Four one-token steps on layer 1 of a packed state pool, by the
+    Pallas kernel (interpret mode; 3 rows take tiles of 1) and by the
+    packed jnp form, against ``_wkv_with_state`` on the unpacked (hs, hs)
+    state: outputs and states agree to fp32 rounding, and the other layers
+    are left as they were."""
+    from repro.kernels import ops, ref
+    from repro.models.rwkv6 import _wkv_with_state, pack_state, unpack_state
+    L = 3
+    rng = np.random.default_rng(hs)
+
+    def draw(shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    s = draw((B, H, hs, hs))
+    pool = pack_state(draw((L, B, H, hs, hs))).at[1].set(pack_state(s))
+    others = np.asarray(pool)[[0, 2]]
+    u = draw((H, hs))
+    step = (functools.partial(ops.wkv6_decode, interpret=True)
+            if form == "kernel" else ref.wkv6_decode_ref)
+    for i in range(4):
+        r, k, v = draw((B, H, hs)), draw((B, H, hs)), draw((B, H, hs))
+        w = jnp.asarray(rng.uniform(0.2, 0.99, (B, H, hs)), jnp.float32)
+        o_want, s = _wkv_with_state(r[:, None], k[:, None], v[:, None],
+                                    w[:, None], u, s)
+        o, pool = step(pool, jnp.int32(1), r, k, v, w, u)
+        np.testing.assert_allclose(
+            np.asarray(o), np.asarray(o_want[:, 0]), rtol=1e-5,
+            atol=1e-5 * float(jnp.abs(o_want).max()), err_msg=f"step {i}")
+        np.testing.assert_allclose(
+            np.asarray(unpack_state(pool[1], hs)), np.asarray(s), rtol=1e-5,
+            atol=1e-5 * float(jnp.abs(s).max()), err_msg=f"step {i}")
+    np.testing.assert_array_equal(np.asarray(pool)[[0, 2]], others)
+
+
+@pytest.mark.parametrize("hs", [16, 64, 96, 128])
+def test_pack_state_round_trip(hs):
+    """Packing is the row-major reshape to 128 lanes where hs divides 128
+    (lane n of row p holds S[p·g + n // hs, n % hs], g = 128 / hs), the
+    state as it is otherwise, and unpacking undoes it."""
+    from repro.models.rwkv6 import pack_state, state_shape, unpack_state
+    s = np.arange(2 * 3 * hs * hs, dtype=np.float32).reshape(2, 3, hs, hs)
+    p = pack_state(jnp.asarray(s))
+    packs = 128 % hs == 0 and hs < 128
+    assert p.shape[2:] == state_shape(hs) == (
+        (hs * hs // 128, 128) if packs else (hs, hs))
+    np.testing.assert_array_equal(np.asarray(unpack_state(p, hs)), s)
+    if packs:
+        g = 128 // hs
+        rows, lanes = np.meshgrid(np.arange(p.shape[2]), np.arange(128),
+                                  indexing="ij")
+        np.testing.assert_array_equal(
+            np.asarray(p), s[:, :, rows * g + lanes // hs, lanes % hs])
 
 
 def test_moe_decode_matches_prefill_no_dropping():

@@ -1,16 +1,18 @@
-"""Every registered tile of the four Pallas kernels compiles for a TPU v5e.
+"""Every registered tile of the five Pallas kernels compiles for a TPU v5e.
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests need no chip.  Operands are at the
 widths of the models that use each kernel: flash_attention at
 qwen2.5-14b's layout (40 q heads over 8 kv heads of 128, S = T = 2048,
-bf16), wkv6 at rwkv6-3b's (40 heads of 64, T = 2048), rglru_scan at
+bf16), wkv6 at rwkv6-3b's (40 heads of 64, T = 2048), wkv6_decode at
+rwkv6-3b's pool (32 layers, 128 rows, 40 heads of 64), rglru_scan at
 recurrentgemma-2b's width (2560) and rmsnorm at d = 5120.  The decode
 steps of rwkv6-3b (capacity 128) and of nemotron3-nano-30b-a3b (capacity
 64, 8 experts held, 1536 positions) are compiled whole, to check that
-they update their pools in place.  The topology is described inside a
-fixture, so only the worker that runs this file loads the TPU library;
-all of these tests live in this one file.
+they update their pools in place, and so is the pool insert of each, to
+check that admitting a request writes one row.  The topology is described
+inside a fixture, so only the worker that runs this file loads the TPU
+library; all of these tests live in this one file.
 """
 import dataclasses
 import os
@@ -24,15 +26,21 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import ops, variants
 from repro.models import Transformer
+from repro.serve.kvpool import (_insert_fn, cache_bytes_per_slot,
+                                infer_batch_axes)
 
 _FLASH = ((1, 2048, 8, 5, 128), (1, 2048, 8, 128), (1, 2048, 8, 128))
 _WKV6 = ((1, 2048, 40, 64),) * 4 + ((40, 64),)
+_WKV6_DECODE = ((32, 128, 40, 32, 128), ()) + ((128, 40, 64),) * 4 + (
+    (40, 64),)
 _RGLRU = ((1, 2048, 2560),) * 2
 _RMSNORM = ((2048, 5120), (5120,))
 
 _DTYPES = {
     "flash_attention": (jnp.bfloat16,) * 3,
     "wkv6": (jnp.bfloat16,) * 3 + (jnp.float32, jnp.bfloat16),
+    "wkv6_decode": (jnp.float32, jnp.int32) + (jnp.bfloat16,) * 3 + (
+        jnp.float32, jnp.bfloat16),
     "rglru_scan": (jnp.float32,) * 2,
     "rmsnorm": (jnp.bfloat16,) * 2,
 }
@@ -42,6 +50,9 @@ _CALLS = {
         lambda q, k, v: ops.flash_attention(q, k, v, causal=True, **kw)),
     "wkv6": lambda kw: (
         lambda r, k, v, w, u: ops.wkv6(r, k, v, w, u, **kw)),
+    "wkv6_decode": lambda kw: (
+        lambda s, l, r, k, v, w, u: ops.wkv6_decode(s, l, r, k, v, w, u,
+                                                    **kw)),
     "rglru_scan": lambda kw: (lambda a, b: ops.rglru_scan(a, b, **kw)),
     "rmsnorm": lambda kw: (lambda x, w: ops.rmsnorm(x, w, **kw)),
 }
@@ -50,6 +61,7 @@ _CALLS = {
 def _cases():
     cases = []
     for kernel, shapes in (("flash_attention", _FLASH), ("wkv6", _WKV6),
+                           ("wkv6_decode", _WKV6_DECODE),
                            ("rglru_scan", _RGLRU), ("rmsnorm", _RMSNORM)):
         for v in variants.variants_for(kernel, shapes):
             cases.append(pytest.param(kernel, shapes, v, id=v.label))
@@ -99,8 +111,28 @@ def test_kernel_tile_compiles_for_v5e(kernel, shapes, variant, one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-_POOL = "f32[32,128,40,64,64]"       # rwkv6-3b's state pool at capacity 128
+_POOL = "f32[32,128,40,32,128]"     # rwkv6-3b's state pool at capacity 128
 _POOL_LAYER_BYTES = 128 * 40 * 64 * 64 * 4
+
+
+def _on(sharding):
+    def on_chip(t):
+        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=sharding)
+    return on_chip
+
+
+def _compile_insert(m, params, cache, max_seq, one_chip):
+    """The pool insert (``kvpool._insert_fn``) of ``cache`` against the
+    cache that a batch-1 prefill of 16 tokens hands back."""
+    tokens = jax.ShapeDtypeStruct((1, 16), jnp.int32, sharding=one_chip)
+    row = jax.tree.map(_on(one_chip), jax.eval_shape(
+        lambda p, t: m.prefill(p, {"tokens": t}, max_seq=max_seq)[1],
+        params, tokens))
+    axes = tuple(infer_batch_axes(m, max_seq))
+    idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return axes, _insert_fn(axes).lower(
+        tuple(jax.tree.leaves(cache)), tuple(jax.tree.leaves(row)), idx,
+        idx).compile()
 
 
 def test_rwkv6_decode_updates_state_pool_in_place(one_chip,
@@ -108,16 +140,16 @@ def test_rwkv6_decode_updates_state_pool_in_place(one_chip,
     """The decode step of rwkv6-3b at its published widths, capacity 128,
     with the cache donated: each layer's rows of the pool are updated in
     place, so the program needs no second pool (its temporaries stay under
-    one layer's state) and copies no whole pool."""
+    one layer's state) and copies no whole pool.  It takes the pool in the
+    layout the insert hands it back in, so nothing relays the pool out
+    between the two programs."""
     m = Transformer(get_config("rwkv6-3b"))
     C = 128
-
-    def on_chip(t):
-        return jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
-
-    params = jax.tree.map(on_chip, jax.eval_shape(m.init, jax.random.key(0)))
-    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: m.init_cache(C, 1)))
-    assert cache["state"].shape == (32, C, 40, 64, 64)
+    params = jax.tree.map(_on(one_chip),
+                          jax.eval_shape(m.init, jax.random.key(0)))
+    cache = jax.tree.map(_on(one_chip),
+                         jax.eval_shape(lambda: m.init_cache(C, 1)))
+    assert cache["state"].shape == (32, C, 40, 32, 128)
     tok = {"tokens": jax.ShapeDtypeStruct((C,), jnp.int32, sharding=one_chip)}
     pos = jax.ShapeDtypeStruct((C,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(m.decode_step, donate_argnums=(1,)).lower(
@@ -133,6 +165,66 @@ def test_rwkv6_decode_updates_state_pool_in_place(one_chip,
         if op and _POOL in rhs[:op.start()]:
             pool_copies.append(line.strip())
     assert not pool_copies, pool_copies
+
+    _, insert = _compile_insert(m, params, cache, 1, one_chip)
+    takes = [f.layout for f in jax.tree.leaves(compiled.input_formats[0][1])]
+    hands = [f.layout for f in jax.tree.leaves(insert.output_formats)]
+    assert takes == hands
+
+
+def _batch_major(layout, ax):
+    """The batch axis is more major than the lane (minor-most) axis, so a
+    slot owns whole 128-lane rows of the pool's tiles, not one lane of
+    every tile."""
+    return layout.major_to_minor[-1] != ax
+
+
+def _hlo_shape(t, batch_ax=None):
+    dims = list(t.shape)
+    if batch_ax is not None:
+        dims[batch_ax] = 1
+    name = {"float32": "f32", "bfloat16": "bf16"}[jnp.dtype(t.dtype).name]
+    return f"{name}[{','.join(map(str, dims))}]"
+
+
+@pytest.mark.parametrize("arch,capacity,max_seq", [
+    ("rwkv6-3b", 128, 1), ("nemotron3-nano-30b-a3b", 64, 1536)])
+def test_pool_insert_writes_one_row(arch, capacity, max_seq, one_chip,
+                                    no_compile_cache):
+    """Admitting a request writes its slot and nothing more.  Compiled for
+    a v5e at the benchmark cells' capacities, the insert takes every pooled
+    leaf batch-major, needs under two slots of temporaries, and copies
+    neither a whole pool nor a row padded out along the batch axis (a
+    batch-1 row laid out with its batch axis in the lanes)."""
+    cfg = get_config(arch)
+    if arch.startswith("nemotron"):
+        cfg = dataclasses.replace(cfg, experts_held=8)
+    m = Transformer(cfg)
+    params = jax.tree.map(_on(one_chip),
+                          jax.eval_shape(m.init, jax.random.key(0)))
+    cache = jax.tree.map(_on(one_chip), jax.eval_shape(
+        lambda: m.init_cache(capacity, max_seq)))
+    axes, insert = _compile_insert(m, params, cache, max_seq, one_chip)
+
+    leaves = jax.tree.leaves(cache)
+    formats = jax.tree.leaves(insert.input_formats[0][0])
+    assert len(formats) == len(leaves) == len(axes)
+    for leaf, fmt, ax in zip(leaves, formats, axes):
+        assert _batch_major(fmt.layout, ax), (leaf.shape, fmt.layout)
+
+    slot = cache_bytes_per_slot(m, max_seq)
+    assert insert.memory_analysis().temp_size_in_bytes < 2 * slot
+
+    pools = {_hlo_shape(t) for t in leaves}
+    rows = {_hlo_shape(t, ax): ax for t, ax in zip(leaves, axes)}
+    bad = []
+    for line in insert.as_text().splitlines():
+        hit = re.search(r"= (\w+\[[\d,]*\])\{(\d+)[^}]*\} copy(-start)?\(",
+                        line)
+        if hit and (hit[1] in pools or (
+                hit[1] in rows and int(hit[2]) == rows[hit[1]])):
+            bad.append(line.strip()[:160])
+    assert not bad, bad
 
 
 def _materialized_copies(hlo_text, shapes):

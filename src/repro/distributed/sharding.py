@@ -257,7 +257,6 @@ class MeshPolicy:
             "embeds": emb_spec,
             "embeds_dec": PartitionSpec(b, None, div(cfg.d_model)),
             "ffn_hidden": PartitionSpec(b, None, div(cfg.d_ff)),
-            "rnn_hidden": PartitionSpec(b, None, div(cfg.d_model)),
             "q5": PartitionSpec(b, None,
                                 div(getattr(cfg, "n_kv_heads", 0) or 1),
                                 None, None),

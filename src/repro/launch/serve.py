@@ -135,8 +135,7 @@ def main():
               f"p50={rep['latency_p50_s']*1e3:.0f}ms "
               f"p99={rep['latency_p99_s']*1e3:.0f}ms  "
               f"occupancy={rep['occupancy']:.2f}")
-        print(f"[serve.engine] tune: {rep['tune']['measurements']} measured "
-              f"/ {rep['tune']['hits']} cached  pool: {rep['pool']}")
+        print(f"[serve.engine] pool: {rep['pool']}")
         return
 
     out = serve(cfg, batch=args.batch, prompt_len=args.prompt_len,

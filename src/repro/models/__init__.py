@@ -1,6 +1,6 @@
 """Model substrate: assigned architectures as one composable Transformer."""
 from .layers import P, Policy, cross_entropy, rms_norm
-from .transformer import Transformer, model_spec
+from .transformer import STACKS, Transformer
 
-__all__ = ["Transformer", "model_spec", "P", "Policy", "cross_entropy",
+__all__ = ["Transformer", "STACKS", "P", "Policy", "cross_entropy",
            "rms_norm"]

@@ -15,8 +15,9 @@ import jax.numpy as jnp
 
 from .layers import P, Policy, apply_rope, rms_norm
 
-__all__ = ["attn_spec", "attn_apply", "attn_decode", "attn_decode_pooled",
-           "init_kv_cache", "blockwise_attention", "decode_attention"]
+__all__ = ["attn_spec", "attn_apply", "attn_with_cache", "attn_decode",
+           "attn_decode_pooled", "init_kv_cache", "blockwise_attention",
+           "decode_attention"]
 
 NEG_INF = -1e30
 
@@ -153,6 +154,45 @@ def attn_apply(params, x, cfg, positions, *,
     w_o = params["w_o"] if policy is None else policy.acts(
         params["w_o"], "w_attn_out")
     return jnp.einsum("bshk,hkd->bsd", o, w_o)
+
+
+def _ring_cache_from_kv(k, v, window: int):
+    """Pack the last ``window`` (roped) k/v into a ring buffer laid out by
+    absolute-position % window (matching the decode-side slot rule)."""
+    B, S, K, D = k.shape
+    W = min(window, S)
+    pos = jnp.arange(S - W, S)
+    slot = pos % window if S >= window else pos
+    ck = jnp.zeros((B, window, K, D), k.dtype).at[:, slot].set(k[:, -W:])
+    cv = jnp.zeros((B, window, K, D), v.dtype).at[:, slot].set(v[:, -W:])
+    cpos = (jnp.zeros((B, window), jnp.int32) - 1).at[:, slot].set(
+        jnp.broadcast_to(pos, (B, W)))
+    return {"k": ck, "v": cv, "pos": cpos}
+
+
+def _full_cache_from_kv(k, v, max_seq: int):
+    B, S, K, D = k.shape
+    ck = jnp.zeros((B, max_seq, K, D), k.dtype).at[:, :S].set(k)
+    cv = jnp.zeros((B, max_seq, K, D), v.dtype).at[:, :S].set(v)
+    cpos = (jnp.zeros((B, max_seq), jnp.int32) - 1).at[:, :S].set(
+        jnp.arange(S))
+    return {"k": ck, "v": cv, "pos": cpos}
+
+
+def attn_with_cache(p, xn, cfg, positions, window, max_seq):
+    """Causal self-attention over the prompt and the cache it leaves: a
+    ring buffer of ``window`` positions, or the full cache of
+    ``max_seq``."""
+    B, S, _ = xn.shape
+    K, G = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    q, k, v = _project_qkv(p, xn, cfg, positions)
+    qr = q.reshape(B, S, K, G, cfg.d_head)
+    o = blockwise_attention(qr, k, v, causal=True, window=window)
+    o = o.reshape(B, S, cfg.n_heads, cfg.d_head)
+    attn_out = jnp.einsum("bshk,hkd->bsd", o, p["w_o"])
+    cache = (_ring_cache_from_kv(k, v, window) if window
+             else _full_cache_from_kv(k, v, max_seq))
+    return attn_out, cache
 
 
 # ---------------------------------------------------------------------------

@@ -10,6 +10,9 @@ shifts.  Training uses a time scan (Pallas chunked kernel on real TPU:
 ``repro.kernels.wkv6``); decode carries (S, x_prev) per layer, S in the
 serving pool's lane-dense form (``state_shape``), stepped on a TPU by
 ``repro.kernels.wkv6_decode``.
+
+The stack of ``layer_pattern="rwkv"``: pre-norm time-mix and channel-mix
+layers, one ``lax.scan`` over stacked parameters.
 """
 from __future__ import annotations
 
@@ -20,9 +23,12 @@ import jax.numpy as jnp
 
 from .layers import P, Policy, rms_norm
 
-__all__ = ["rwkv6_spec", "rwkv6_time_mix", "rwkv6_time_mix_step",
-           "rwkv6_channel_mix", "init_rwkv_cache", "state_shape",
-           "pack_state", "unpack_state", "wkv6_scan_ref"]
+__all__ = ["EXACT_PROMPTS", "spec", "forward", "init_cache", "decode",
+           "rwkv6_spec", "rwkv6_time_mix", "rwkv6_time_mix_step",
+           "rwkv6_channel_mix", "state_shape", "pack_state", "unpack_state",
+           "wkv6_scan_ref"]
+
+EXACT_PROMPTS = True
 
 LORA_R = 32
 _MIX = ("w", "k", "v", "r", "g")
@@ -109,14 +115,12 @@ def _time_mix_out(p, o, g, x, policy):
     return (o * g) @ p["w_out"]
 
 
-def rwkv6_time_mix(p, x, cfg, *, x_prev=None,
-                   policy: Optional[Policy] = None,
+def rwkv6_time_mix(p, x, cfg, *, policy: Optional[Policy] = None,
                    use_pallas: bool = False):
     """x: (B, T, d), from a zero state.  Returns (out, (new_x_prev,
     final state (B, H, hs, hs)))."""
     B, T, d = x.shape
-    if x_prev is None:
-        x_prev = jnp.zeros((B, d), x.dtype)
+    x_prev = jnp.zeros((B, d), x.dtype)
     r, k, v, w, g, u = _time_mix_inputs(p, x, _token_shift(x, x_prev), cfg)
     if use_pallas:
         from repro.kernels import ops as kops
@@ -193,15 +197,72 @@ def unpack_state(s, hs: int):
     return s.reshape(s.shape[:-2] + (hs, hs))
 
 
-def init_rwkv_cache(cfg, n_layers: int, batch: int, dtype=jnp.bfloat16):
+def spec(cfg) -> Dict[str, Any]:
+    n = cfg.n_layers
+    return {"layers": {
+        "ln1": P((n, cfg.d_model), ("layers", "embed"), init="ones"),
+        "ln2": P((n, cfg.d_model), ("layers", "embed"), init="ones"),
+        "rwkv": rwkv6_spec(cfg, (n,), ("layers",)),
+    }}
+
+
+def forward(params, x, cfg, positions, policy, *, collect=False,
+            use_pallas=False, **_):
+    def body(carry, lp):
+        x, aux = carry
+        o, (tm_x, state) = rwkv6_time_mix(
+            lp["rwkv"]["tm"], rms_norm(x, lp["ln1"], cfg.norm_eps), cfg,
+            policy=policy, use_pallas=use_pallas and not collect)
+        h = x + o
+        o2, cm_x = rwkv6_channel_mix(
+            lp["rwkv"]["cm"], rms_norm(h, lp["ln2"], cfg.norm_eps), cfg)
+        out = h + o2
+        if policy is not None:
+            out = policy.acts(out, "embeds")
+        # the state in the pool's packed form (one relayout per request)
+        cache = ({"tm_x": tm_x, "cm_x": cm_x, "state": pack_state(state)}
+                 if collect else {})
+        return (out, aux), cache
+    (x, aux), caches = jax.lax.scan(
+        jax.checkpoint(body), (x, 0.0), params["layers"])
+    return x, aux, caches
+
+
+def init_cache(cfg, batch: int, max_seq: int, dtype=jnp.bfloat16, **_):
     d = cfg.d_model
     hs = cfg.rwkv_head_size
-    H = d // hs
+    H, n = d // hs, cfg.n_layers
     return {
-        "tm_x": jnp.zeros((n_layers, batch, d), dtype),
-        "cm_x": jnp.zeros((n_layers, batch, d), dtype),
-        "state": jnp.zeros((n_layers, batch, H) + state_shape(hs),
-                           jnp.float32),
+        "tm_x": jnp.zeros((n, batch, d), dtype),
+        "cm_x": jnp.zeros((n, batch, d), dtype),
+        "state": jnp.zeros((n, batch, H) + state_shape(hs), jnp.float32),
     }
 
 
+def decode(params, x, cfg, cache, pos, policy):
+    # The pool rides in the carry and layer l's rows are updated in place:
+    # scanned as xs/ys it would be stacked into a second pool and copied
+    # back whole every step.
+    def body(carry, xs):
+        x, pool = carry
+        l, lp = xs
+        c = {k: jax.lax.dynamic_index_in_dim(pool[k], l, 0,
+                                             keepdims=False)
+             for k in ("tm_x", "cm_x")}
+        xn = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        o, (tm_x, state) = rwkv6_time_mix_step(
+            lp["rwkv"]["tm"], xn, cfg, pool["state"], l,
+            x_prev=c["tm_x"], policy=policy)
+        h = x + o
+        hn = rms_norm(h, lp["ln2"], cfg.norm_eps)
+        o2, cm_x = rwkv6_channel_mix(lp["rwkv"]["cm"], hn, cfg,
+                                     x_prev=c["cm_x"])
+        new = {"tm_x": tm_x, "cm_x": cm_x}
+        pool = {k: jax.lax.dynamic_update_index_in_dim(
+                    pool[k], new[k].astype(pool[k].dtype), l, 0)
+                for k in new} | {"state": state}
+        return (h + o2, pool), None
+    n_layers = cache["state"].shape[0]
+    (x, new_cache), _ = jax.lax.scan(
+        body, (x, cache), (jnp.arange(n_layers), params["layers"]))
+    return x, new_cache

@@ -3,8 +3,7 @@
 ``request``/``queue`` hold the host-side lifecycle and admission policy,
 ``kvpool`` the fixed-capacity device slot allocator, ``batcher`` the
 decode-batch occupancy bookkeeping, ``engine`` the driver loop with
-shape-bucketed prefills mapped onto the persistent tune cache, and
-``load`` the seeded open-loop trace generator the benchmark replays.
+shape-bucketed prefills, and ``load`` the seeded open-loop trace generator the benchmark replays.
 """
 from .batcher import JOIN_POLICIES, ContinuousBatcher
 from .engine import Engine, ServeRuntime, bucket_len, derive_capacity

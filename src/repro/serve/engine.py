@@ -20,18 +20,13 @@ it owns); the decode loop carries tokens/positions/output buffer ON
 DEVICE, so steady-state host↔device traffic is zero; generated tokens
 come back in one batched fetch per retirement flush (delegatestore).
 
-Shape buckets & the plan cache: prompts are right-padded to power-of-two
-buckets (exact lengths for recurrent archs, where padding would corrupt
-the carried state) so repeated traffic reuses a handful of compiled
-prefill shapes.  Each bucket maps onto a persistent ``TuneCache`` entry
-keyed by (cfg, backend fingerprint, bucket dims): the first time a
-bucket is seen across ALL processes it is measured once (blocking), and
-every later run — including fresh engines in fresh processes — looks it
-up and stays on the pure async path with zero online measurements.
+Shape buckets: prompts are right-padded to power-of-two buckets, so
+repeated traffic reuses a handful of compiled prefill shapes, except where
+the model's stack carries recurrent state (``EXACT_PROMPTS``), which
+padding would corrupt: there each prompt runs at its exact length.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 from typing import Any, Dict, List, Optional
 
@@ -47,7 +42,7 @@ __all__ = ["ServeRuntime", "Engine", "derive_capacity", "bucket_len"]
 
 def bucket_len(prompt_len: int, max_seq: int, *, exact: bool) -> int:
     """Padded prompt length for a shape bucket: next power of two (min 8),
-    capped at ``max_seq``.  ``exact`` archs (recurrent state) get their
+    capped at ``max_seq``.  ``exact`` stacks (recurrent state) get the
     true length — padding would pollute the carried state."""
     if exact:
         return prompt_len
@@ -65,8 +60,8 @@ def derive_capacity(model, max_seq: int, device_bytes: int,
 class ServeRuntime:
     """Compiled machinery shared by engines (and by benchmark modes, so
     continuous-vs-static comparisons never pay a recompile): resident
-    params, the bucketed prefill jit, the whole-batch decode jit, the
-    admission row-write jit, and the bucket↔tunecache bookkeeping."""
+    params, the bucketed prefill jit, the whole-batch decode jit and the
+    admission row-write jit."""
 
     def __init__(self, cfg, *, max_seq: int, backend: Any = None,
                  params: Any = None, seed: int = 0, use_pallas: bool = False):
@@ -75,7 +70,6 @@ class ServeRuntime:
 
         from repro.core.backend import get_backend
         from repro.core.residency import DeviceResidency
-        from repro.core.tunecache import default_cache
         from repro.models import Transformer
 
         self.cfg = cfg
@@ -84,8 +78,7 @@ class ServeRuntime:
         # two logical streams: 0 = decode compute, 1 = prefill + fetches
         self.be = be.variant(n_streams=max(be.n_streams, 2))
         self.model = Transformer(cfg, use_pallas=use_pallas)
-        self.exact_buckets = cfg.layer_pattern in ("rwkv", "griffin",
-                                                  "nemotron_h")
+        self.exact_buckets = self.model.stack.EXACT_PROMPTS
 
         # weights resident once, through the instrumented residency layer
         owned = params is None
@@ -119,13 +112,6 @@ class ServeRuntime:
         # reused WITHOUT a host sync; everything downloads in one batch
         self._park = jax.jit(self._park_impl, donate_argnums=(0,))
         self._jnp = jnp
-
-        # bucket -> "measured" | "cached"; persisted across processes via
-        # the tune cache (None when REPRO_TUNE_CACHE is unset)
-        self.tune = default_cache()
-        self._buckets: Dict[int, str] = {}
-        self.tune_measurements = 0
-        self.tune_hits = 0
 
     # -- jitted bodies (each jit is named after its method) ------------------
     def _prefill_impl(self, params, batch, last_pos):
@@ -176,22 +162,9 @@ class ServeRuntime:
         return bucket_len(prompt_len, self.max_seq,
                           exact=self.exact_buckets)
 
-    def _bucket_fingerprint(self, padded: int) -> str:
-        from repro.core.tunecache import (COST_MODEL_VERSION, _sha,
-                                          backend_fingerprint)
-        return _sha({
-            "cost_model_version": COST_MODEL_VERSION,
-            "cfg": dataclasses.asdict(self.cfg),
-            "backend": backend_fingerprint(self.be),
-            "bucket": {"padded_len": padded, "max_seq": self.max_seq},
-        })
-
     def prefill_request(self, req: Request):
         """Pad to the request's bucket, run the prefill on logical stream 1,
-        and return (last-real-token logits, cache tree).  Cold buckets are
-        measured once (blocking) and stored in the persistent tune cache;
-        warm buckets stay fully asynchronous."""
-        import jax
+        and return (last-real-token logits, cache tree), asynchronously."""
         jnp = self._jnp
         cfg, L = self.cfg, req.prompt_len
         padded = self.bucket_of(L)
@@ -204,28 +177,6 @@ class ServeRuntime:
             buf[0, :L] = req.prompt
             batch = {"tokens": jnp.asarray(buf)}
         last_pos = jnp.asarray([L - 1], jnp.int32)
-
-        state = self._buckets.get(padded)
-        if state is None:
-            slot = f"serve--{cfg.name}--p{padded}"
-            fp = self._bucket_fingerprint(padded)
-            hit = self.tune.lookup(slot, fp) if self.tune else None
-            if hit is not None:
-                self._buckets[padded] = "cached"
-                self.tune_hits += 1
-            else:
-                t0 = time.perf_counter()
-                logits, cache = self._prefill(self.params, batch, last_pos)
-                jax.block_until_ready(logits)
-                ms = (time.perf_counter() - t0) * 1e3
-                self.tune_measurements += 1
-                self._buckets[padded] = "measured"
-                if self.tune:
-                    self.tune.store(slot, fp, {"prefill_ms": ms,
-                                               "padded_len": padded})
-                return self.be.track(logits, stream=1), cache
-        else:
-            self.tune_hits += 1
         logits, cache = self._prefill(self.params, batch, last_pos)
         return self.be.track(logits, stream=1), cache
 
@@ -382,12 +333,6 @@ class Engine:
             "fetch_batches": self.fetch_batches,
             "queue": self.queue.stats(),
             "pool": self.pool.stats(),
-            "tune": {
-                "measurements": rt.tune_measurements,
-                "hits": rt.tune_hits,
-                "buckets": dict(rt._buckets),
-                "persistent": rt.tune is not None,
-            },
             "residency": {
                 "weights_h2d_bytes": rt.weights_bytes,
                 "h2d_transfers": rt.residency.stats.h2d_transfers,

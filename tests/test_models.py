@@ -81,6 +81,60 @@ def test_decode_matches_prefill(name):
                                atol=2e-3 * np.abs(b).max())
 
 
+def test_unknown_layer_pattern_raises():
+    """A misspelt layer pattern fails where the model is built, naming the
+    known ones, instead of building a dense stack."""
+    cfg = dataclasses.replace(reduced(get_config("rwkv6-3b")),
+                              layer_pattern="rwkv7")
+    with pytest.raises(ValueError, match="rwkv7") as err:
+        Transformer(cfg)
+    for known in ("full", "griffin", "nemotron_h", "rwkv"):
+        assert repr(known) in str(err.value)
+
+
+@pytest.mark.parametrize("name", ["internlm2-20b", "recurrentgemma-2b",
+                                  "rwkv6-3b", "nemotron3-nano-30b-a3b"])
+def test_exact_prompts_match_padding(name):
+    """One decode step after a prompt prefilled at its exact length, and
+    after the same prompt right-padded to the serving engine's bucket (its
+    real last token picked by ``last_pos``): the next-token logits agree
+    exactly where the stack says padding is harmless (``EXACT_PROMPTS``
+    False), and differ where the cache carries recurrent state."""
+    from repro.serve import bucket_len
+    cfg = reduced(get_config(name))
+    m = Transformer(cfg)
+    # no leaf at its zero init: Griffin's conv starts at zero, and with it
+    # the input of its recurrence
+    leaves, treedef = jax.tree.flatten(m.init(jax.random.key(2)))
+    keys = jax.random.split(jax.random.key(3), len(leaves))
+    params = treedef.unflatten([
+        p + 0.1 * jax.random.normal(k, p.shape, p.dtype)
+        for p, k in zip(leaves, keys)])
+    B, L, max_seq = 2, 11, 32
+    padded = bucket_len(L, max_seq, exact=False)
+    assert padded > L
+    toks = RNG.integers(1, cfg.vocab, (B, L + 1))
+    prompt = np.zeros((B, padded), np.int32)
+    prompt[:, :L] = toks[:, :L]
+    nxt = {"tokens": jnp.asarray(toks[:, L], jnp.int32)}
+    pos = jnp.full((B,), L, jnp.int32)
+
+    logits = []
+    for batch, last in ((toks[:, :L], None), (prompt, [L - 1] * B)):
+        _, cache = m.prefill(params, {"tokens": jnp.asarray(batch,
+                                                            jnp.int32)},
+                             max_seq=max_seq,
+                             last_pos=None if last is None
+                             else jnp.asarray(last, jnp.int32))
+        ld, _ = m.decode_step(params, cache, nxt, pos)
+        logits.append(np.asarray(ld, np.float32))
+    exact, pad = logits
+    agree = np.allclose(pad, exact, rtol=2e-3,
+                        atol=2e-3 * np.abs(exact).max())
+    assert agree == (not m.stack.EXACT_PROMPTS), (
+        name, np.abs(pad - exact).max())
+
+
 def test_rwkv_donated_decode_steps_match_prefill():
     """Eight donated decode steps in a row, teacher-forced, after
     prefill(S): step i's logits == last logits of prefill(S + i + 1).  The

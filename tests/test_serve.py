@@ -5,9 +5,7 @@ admission queue's policies and token budget, KV-slot pool churn /
 bit-reuse / leak detection — then the engine end to end: token-exact
 equality against a per-request reference decode (padded buckets on a
 dense arch, exact buckets on rwkv), the gen=1 degenerate case, the
-static-join baseline, over-capacity queueing, donation defaults, and
-the shape-bucket → persistent tunecache mapping (warm runs measure
-nothing).
+static-join baseline, over-capacity queueing and donation defaults.
 """
 import math
 
@@ -258,16 +256,12 @@ def _reference_decode(rt, req):
 
 @pytest.fixture(scope="module")
 def rwkv_rt(rwkv_cfg):
-    rt = ServeRuntime(rwkv_cfg, max_seq=MAX_SEQ, seed=0)
-    rt.tune = None     # per-test cache isolation is function-scoped
-    return rt
+    return ServeRuntime(rwkv_cfg, max_seq=MAX_SEQ, seed=0)
 
 
 @pytest.fixture(scope="module")
 def dense_rt(dense_cfg):
-    rt = ServeRuntime(dense_cfg, max_seq=MAX_SEQ, seed=0)
-    rt.tune = None
-    return rt
+    return ServeRuntime(dense_cfg, max_seq=MAX_SEQ, seed=0)
 
 
 def _mixed_trace(rids, lens, gens):
@@ -399,32 +393,6 @@ class TestDonationDefault:
 
 
 # ---------------------------------------------------------------------------
-# Shape buckets ↔ persistent tune cache (tentpole acceptance)
-# ---------------------------------------------------------------------------
-
-class TestBucketTuneCache:
-    def test_warm_runtime_measures_nothing(self, rwkv_cfg):
-        """A fresh runtime in the same (isolated) cache dir must find every
-        bucket already measured: repeated traffic is pure cache hits."""
-        reqs = lambda: [_req(i, L=8, gen=2) for i in range(3)]  # noqa: E731
-        rt1 = ServeRuntime(rwkv_cfg, max_seq=16, seed=0)
-        assert rt1.tune is not None     # conftest points REPRO_TUNE_CACHE
-        Engine(rt1, capacity=2).run(reqs(), respect_arrivals=False)
-        assert rt1.tune_measurements == 1          # one bucket, one measure
-        assert rt1._buckets == {8: "measured"}
-
-        rt2 = ServeRuntime(rwkv_cfg, max_seq=16, seed=0)
-        Engine(rt2, capacity=2).run(reqs(), respect_arrivals=False)
-        assert rt2.tune_measurements == 0          # warm: zero measurements
-        assert rt2.tune_hits >= 3
-        assert rt2._buckets == {8: "cached"}
-
-    def test_fingerprint_varies_with_bucket(self, rwkv_cfg):
-        rt = ServeRuntime(rwkv_cfg, max_seq=16, seed=0)
-        assert (rt._bucket_fingerprint(8) != rt._bucket_fingerprint(16))
-
-
-# ---------------------------------------------------------------------------
 # launch.serve (satellite b) + load generator
 # ---------------------------------------------------------------------------
 
@@ -473,7 +441,7 @@ class TestLoadGenerator:
 class TestServeBenchSmoke:
     def test_quick_bench_invariants(self, tmp_path):
         """The CI smoke: tiny trace, both modes finish everything, p99
-        finite, zero leaks, warm run measures nothing (no speedup gate —
+        finite, zero leaks (no speedup gate —
         scheduling wins need a bigger trace than a unit test should pay
         for)."""
         import sys
@@ -484,7 +452,6 @@ class TestServeBenchSmoke:
             sys.path.pop(0)
         row = serve_bench.bench(arch="rwkv6-3b", n_requests=8, capacity=2,
                                 max_seq=32, seed=0, gate=False)
-        assert row["warm_tune_measurements"] == 0
         assert row["pool"]["in_use"] == 0
         assert math.isfinite(row["continuous"]["latency_p99_s"])
         assert math.isfinite(row["static"]["latency_p99_s"])

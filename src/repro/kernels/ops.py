@@ -14,13 +14,15 @@ import jax
 import jax.numpy as jnp
 
 from . import flash_attention as _fa
+from . import mamba2_decode as _m2d
 from . import rglru_scan as _rg
 from . import rmsnorm as _rn
+from . import variants as _var
 from . import wkv6 as _wkv
 from . import wkv6_decode as _wkd
 
 __all__ = ["flash_attention", "rglru_scan", "wkv6", "wkv6_decode",
-           "rmsnorm"]
+           "mamba2_decode", "rmsnorm"]
 
 
 @functools.partial(jax.custom_vjp,
@@ -112,6 +114,23 @@ def wkv6_decode(state, layer, r, k, v, w, u, *, block_b: int = 2,
     o = (y.reshape(B, H, N // hs, hs).sum(2)
          + v * jnp.sum(r * u * k, axis=-1, keepdims=True))
     return o, state
+
+
+@functools.partial(jax.jit, static_argnames=("block_b", "block_h",
+                                             "interpret"))
+def mamba2_decode(state, layer, decay, u, b, c, *, block_b: int = 1,
+                  block_h: int = 64, interpret: bool = False):
+    """One token against block ``layer`` of the pooled Mamba-2 state (L,
+    B, H, P, N) fp32, updated in place; decay: (B, H); u: (B, H, P); b, c:
+    (B, G, N).  Returns (y (B, H, P) fp32 without the D term, state), as
+    ``ref.mamba2_decode_ref``."""
+    (B, H, P), (G, N) = u.shape, b.shape[1:]
+    # the largest tiles up to the asked ones that the kernel can take
+    bb = _var._divisor_at_most(block_b, B)
+    bh = _var._mamba2_decode_head_tile(block_h, H, G, P, N)
+    return _m2d.mamba2_decode_pooled(state, layer, decay, u, b, c,
+                                     block_b=bb, block_h=bh,
+                                     interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",

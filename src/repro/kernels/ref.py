@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["flash_attention_ref", "rglru_scan_ref", "wkv6_ref",
-           "wkv6_decode_ref", "rmsnorm_ref"]
+           "wkv6_decode_ref", "mamba2_decode_ref", "rmsnorm_ref"]
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
@@ -78,6 +78,20 @@ def wkv6_decode_ref(state, layer, r, k, v, w, u):
     s = w[..., :, None] * s + kv
     return o, jax.lax.dynamic_update_index_in_dim(
         state, s.reshape(packed), layer, 0)
+
+
+def mamba2_decode_ref(state, layer, decay, u, b, c):
+    """One Mamba-2 token against block ``layer`` of a pooled state (L, B,
+    H, P, N) fp32: h' = decay·h + u ⊗ B, y = h'·C, head h reading group
+    h // (H / G) of b, c (B, G, N); decay: (B, H); u: (B, H, P).  Returns
+    (y (B, H, P) fp32 without the D term, the pool with the block's rows
+    advanced)."""
+    H = u.shape[1]
+    s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    b, c = (jnp.repeat(t, H // t.shape[1], axis=1) for t in (b, c))
+    s = decay[..., None, None] * s + u[..., None] * b[:, :, None, :]
+    y = jnp.einsum("bhpn,bhn->bhp", s, c, precision="highest")
+    return y, jax.lax.dynamic_update_index_in_dim(state, s, layer, 0)
 
 
 def rmsnorm_ref(x, w, *, eps: float = 1e-6):

@@ -153,7 +153,8 @@ def _wkv6_roofline(shapes, itemsizes, params):
 #     u (H, hs) -------------------------------------------------------------
 
 def _divisor_at_most(block: int, axis: int) -> int:
-    # mirror ops.wkv6_decode: the largest tile up to block dividing axis
+    # mirror ops.wkv6_decode (ops.mamba2_decode calls it): the largest
+    # tile up to block dividing axis
     return max(d for d in range(1, min(int(block), int(axis)) + 1)
                if axis % d == 0)
 
@@ -189,6 +190,57 @@ def _wkv6_decode_roofline(shapes, itemsizes, params):
     bytes_ = 4 * (2 * B * H * R * N            # one layer's state, in and out
                   + 3 * B * (H // params["block_h"]) * R * lanes   # r, k, w
                   + 2 * B * H * N)             # v in, y out
+    return flops, float(bytes_)
+
+
+# --- mamba2_decode: state (L, B, H, P, N); layer (); decay (B, H);
+#     u (B, H, P); b, c (B, G, N) ----------------------------------------
+
+def _mamba2_decode_head_tile(block: int, H: int, G: int, P: int,
+                             N: int) -> int:
+    # the head tile ops.mamba2_decode launches: the largest up to block
+    # that divides the heads, holds whole (N, N) squares of N / P heads
+    # and whole groups or a part of one, and fills whole sublane tiles
+    # of y; else all heads
+    k, hpg = N // P, H // G
+    return max((d for d in range(k, min(int(block), H) + 1)
+                if H % d == 0 and d % k == 0 and (d // k) % 8 == 0
+                and (d % hpg == 0 or hpg % d == 0)), default=H)
+
+
+def _mamba2_decode_validate(shapes, params) -> Optional[Params]:
+    (L, B, H, P, N), G = shapes[0], shapes[4][1]
+    return {"block_b": _divisor_at_most(params["block_b"], B),
+            "block_h": _mamba2_decode_head_tile(params["block_h"], H, G,
+                                                P, N)}
+
+
+def _mamba2_decode_groups(shapes, params) -> int:
+    """Rows of B and C one head tile reads: its groups, or the one group
+    it is part of."""
+    H, G = shapes[0][2], shapes[4][1]
+    return max(params["block_h"] // (H // G), 1)
+
+
+def _mamba2_decode_workset(shapes, itemsizes, params):
+    (L, B, H, P, N) = shapes[0]
+    bb, bh = params["block_b"], params["block_h"]
+    gt = _mamba2_decode_groups(shapes, params)
+    # fp32 state tile in and out, decay, u, B and C in, y out, each
+    # double-buffered
+    return float(2 * 4 * bb * (2 * bh * P * N + bh + bh * P + 2 * gt * N
+                               + bh * P))
+
+
+def _mamba2_decode_roofline(shapes, itemsizes, params):
+    (L, B, H, P, N) = shapes[0]
+    gt = _mamba2_decode_groups(shapes, params)
+    # per state element: decay·h, u·B, their sum, ·C and the sum over n
+    flops = 5.0 * B * H * P * N
+    bytes_ = 4 * (2 * B * H * P * N            # one block's state, in and out
+                  + B * H + B * H * P          # decay, u
+                  + 2 * B * (H // params["block_h"]) * gt * N   # B, C
+                  + B * H * P)                 # y out
     return flops, float(bytes_)
 
 
@@ -284,6 +336,13 @@ KERNELS: Dict[str, dict] = {
         "validate": _wkv6_decode_validate,
         "roofline": _wkv6_decode_roofline,
         "workset": _wkv6_decode_workset,
+    },
+    "mamba2_decode": {
+        "grid": {"block_b": (1, 2), "block_h": (32, 64)},
+        "defaults": {"block_b": 1, "block_h": 64},
+        "validate": _mamba2_decode_validate,
+        "roofline": _mamba2_decode_roofline,
+        "workset": _mamba2_decode_workset,
     },
     "rglru_scan": {
         # 256 is absent: at recurrentgemma-2b's width (D = 2560) its fp32

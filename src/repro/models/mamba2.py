@@ -12,7 +12,8 @@
 The state h is (heads, head_dim, state) per sequence, kept in float32.
 Prefill runs the chunked SSD form (chunks of ``CHUNK`` tokens: within a
 chunk the recurrence is a masked product of decays, across chunks a scan
-carries the state); decode is one step against the carried state and the
+carries the state); decode is one step against the serving pool's state,
+stepped in place on a TPU by ``repro.kernels.mamba2_decode``, and the
 conv window of the last w-1 inputs.
 """
 from __future__ import annotations
@@ -154,18 +155,23 @@ def mamba2_apply(p, x, cfg, *, collect: bool = False):
     return out, ({"ssm": h, "conv": window} if collect else {})
 
 
-def mamba2_step(p, x, cfg, ssm, window):
-    """One token.  x: (B, 1, d); ssm: (B, H, P, N) float32; window: (B,
-    w-1, conv_dim).  Returns (out (B, 1, d), new ssm, new window)."""
+def mamba2_step(p, x, cfg, pool, layer, window):
+    """One token.  x: (B, 1, d); pool: the pooled state (L, B, H, P, N)
+    float32, of which block ``layer``'s rows are advanced in place;
+    window: (B, w-1, conv_dim).  Returns (out (B, 1, d), pool, new
+    window)."""
+    from repro.kernels import ops as kops
+    from repro.kernels import ref as kref
     z, xbc, dt = _split_in(p, x, cfg)
     xbc, window = _conv1d({"conv_w": p["conv_w"], "conv_b": p["conv_b"]},
                           xbc, cfg.mamba_conv, state=window)
     xs, b, c = _split_xbc(jax.nn.silu(xbc[:, 0]), cfg)   # (B, H, P), (B, G, N)
     dt = jax.nn.softplus(dt[:, 0] + p["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
-    b, c = _heads(b, cfg), _heads(c, cfg)                # (B, H, N)
-    ssm = (jnp.exp(dt * a)[..., None, None] * ssm
-           + (dt[..., None] * xs)[..., None] * b[:, :, None, :])
-    y = jnp.einsum("bhpn,bhn->bhp", ssm, c, precision="highest")
+    # the kernel is chosen by the compile target, not by the process's
+    # default backend: a CPU process may compile for a TPU
+    y, pool = jax.lax.platform_dependent(
+        pool, layer, jnp.exp(dt * a), dt[..., None] * xs, b, c,
+        tpu=kops.mamba2_decode, default=kref.mamba2_decode_ref)
     y = y + p["D"].astype(jnp.float32)[:, None] * xs
-    return _out(p, y[:, None], z, cfg, x.dtype), ssm, window
+    return _out(p, y[:, None], z, cfg, x.dtype), pool, window

@@ -78,11 +78,11 @@ def decode(params, x, cfg, pool, pos, policy):
             xn = rms_norm(x, lp["norm"], cfg.norm_eps)
             if kind == "mamba2":
                 o, ssm, win = mamba2_step(lp["mamba2"], xn, cfg,
-                                          pool["ssm"][i], pool["conv"][i])
-                pool = dict(pool, ssm=jax.lax.dynamic_update_index_in_dim(
-                    pool["ssm"], ssm, i, 0),
-                    conv=jax.lax.dynamic_update_index_in_dim(
-                        pool["conv"], win.astype(pool["conv"].dtype), i, 0))
+                                          pool["ssm"], i, pool["conv"][i])
+                pool = dict(pool, ssm=ssm,
+                            conv=jax.lax.dynamic_update_index_in_dim(
+                                pool["conv"], win.astype(pool["conv"].dtype),
+                                i, 0))
             elif kind == "moe":
                 o, _ = moe_held_apply(lp["moe"], xn, cfg)
             else:

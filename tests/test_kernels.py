@@ -127,6 +127,9 @@ _FLASH_SHAPES = ((1, 128, 1, 2, 8), (1, 128, 1, 8), (1, 128, 1, 8))
 _WKV6_SHAPES = ((1, 128, 2, 8),) * 4 + ((2, 8),)
 _WKV6_DECODE_SHAPES = ((2, 4, 40, 32, 128), ()) + ((4, 40, 64),) * 4 + (
     (40, 64),)
+# L=2, B=4, H=16, G=2 (8 heads to a group), P=64, N=128
+_MAMBA2_DECODE_SHAPES = ((2, 4, 16, 64, 128), (), (4, 16), (4, 16, 64),
+                         (4, 2, 128), (4, 2, 128))
 _RGLRU_SHAPES = ((1, 256, 8),) * 2
 _RMSNORM_SHAPES = ((128, 32), (32,))
 
@@ -136,6 +139,7 @@ def _variant_cases():
     for kernel, shapes in (("flash_attention", _FLASH_SHAPES),
                            ("wkv6", _WKV6_SHAPES),
                            ("wkv6_decode", _WKV6_DECODE_SHAPES),
+                           ("mamba2_decode", _MAMBA2_DECODE_SHAPES),
                            ("rglru_scan", _RGLRU_SHAPES),
                            ("rmsnorm", _RMSNORM_SHAPES)):
         for v in variants.variants_for(kernel, shapes):
@@ -145,10 +149,12 @@ def _variant_cases():
 
 def test_registry_covers_every_kernel():
     assert set(variants.kernel_names()) == {
-        "flash_attention", "wkv6", "wkv6_decode", "rglru_scan", "rmsnorm"}
+        "flash_attention", "wkv6", "wkv6_decode", "mamba2_decode",
+        "rglru_scan", "rmsnorm"}
     for kernel, shapes in (("flash_attention", _FLASH_SHAPES),
                            ("wkv6", _WKV6_SHAPES),
                            ("wkv6_decode", _WKV6_DECODE_SHAPES),
+                           ("mamba2_decode", _MAMBA2_DECODE_SHAPES),
                            ("rglru_scan", _RGLRU_SHAPES),
                            ("rmsnorm", _RMSNORM_SHAPES)):
         vs = variants.variants_for(kernel, shapes)
@@ -196,6 +202,18 @@ def test_every_registry_variant_matches_ref(kernel, shapes, variant):
                                    rtol=1e-5, atol=1e-4)
         np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
                                    rtol=1e-6, atol=1e-6)
+    elif kernel == "mamba2_decode":
+        state = _rand(shapes[0], jnp.float32)
+        decay = jnp.asarray(RNG.uniform(0.2, 0.99, shapes[2])
+                            .astype(np.float32))
+        u, b, c = (_rand(sh, jnp.float32) for sh in shapes[3:6])
+        y, s = ops.mamba2_decode(state, 1, decay, u, b, c, **kw)
+        y_ref, s_ref = ref.mamba2_decode_ref(state, 1, decay, u, b, c)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                                   rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(s[0]), np.asarray(state[0]))
     elif kernel == "rglru_scan":
         a = jnp.asarray(RNG.uniform(0.4, 0.999, shapes[0])
                         .astype(np.float32))

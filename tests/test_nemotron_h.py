@@ -113,6 +113,66 @@ def test_prefill_then_pooled_decode_matches_reference():
                                atol=2e-5 * np.abs(want).max())
 
 
+def _step_before(p, x, cfg, pool, i, window):
+    """``mamba2_step`` as it was before the pooled kernel: block i's
+    state sliced out of the pool, updated, read out with an einsum and
+    written back."""
+    from repro.models import mamba2 as m2
+    z, xbc, dt = m2._split_in(p, x, cfg)
+    xbc, window = m2._conv1d({"conv_w": p["conv_w"], "conv_b": p["conv_b"]},
+                             xbc, cfg.mamba_conv, state=window)
+    xs, b, c = m2._split_xbc(jax.nn.silu(xbc[:, 0]), cfg)
+    dt = jax.nn.softplus(dt[:, 0] + p["dt_bias"].astype(jnp.float32))
+    a = -jnp.exp(p["A_log"].astype(jnp.float32))
+    b, c = m2._heads(b, cfg), m2._heads(c, cfg)
+    ssm = (jnp.exp(dt * a)[..., None, None] * pool[i]
+           + (dt[..., None] * xs)[..., None] * b[:, :, None, :])
+    y = jnp.einsum("bhpn,bhn->bhp", ssm, c, precision="highest")
+    y = y + p["D"].astype(jnp.float32)[:, None] * xs
+    return (m2._out(p, y[:, None], z, cfg, x.dtype), pool.at[i].set(ssm),
+            window)
+
+
+@pytest.mark.parametrize("path", ["kernel", "reference"])
+def test_pooled_mamba2_step_matches_slice_and_write_back(path, monkeypatch):
+    """One ``mamba2_step`` against block 1 of a two-block pool, through
+    the Pallas kernel (interpret mode, put in the reference's place, which
+    the CPU compile target picks) or through the reference: the output,
+    the window and block 1's state agree with the slice-update-write-back
+    form to float32 rounding, and block 0 is left bit for bit."""
+    from repro.kernels import ops as kops
+    from repro.kernels import ref as kref
+    from repro.models import mamba2 as m2
+    calls = []
+    if path == "kernel":
+        def kernel(*args):
+            calls.append(1)
+            return kops.mamba2_decode(*args, interpret=True)
+        monkeypatch.setattr(kref, "mamba2_decode_ref", kernel)
+    rng = np.random.default_rng(13)   # the module's RNG feeds later tests
+    cfg = small()
+    p = next(b["mamba2"] for b in REF.init(ref_cfg(cfg), 5, "float32")[
+        "blocks"] if "mamba2" in b)
+    p = dict(p, A_log=jnp.asarray(rng.uniform(-1, 1, cfg.mamba_heads),
+                                  jnp.float32),
+             dt_bias=jnp.asarray(rng.uniform(-1, 1, cfg.mamba_heads),
+                                 jnp.float32))
+    B = 3
+    pool = jnp.asarray(rng.standard_normal(
+        (2, B, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state)),
+        jnp.float32)
+    window = jnp.asarray(rng.standard_normal(
+        (B, cfg.mamba_conv - 1, m2.conv_dim(cfg))), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((B, 1, cfg.d_model)), jnp.float32)
+    want = _step_before(p, x, cfg, pool, 1, window)
+    got = m2.mamba2_step(p, x, cfg, pool, 1, window)
+    assert len(calls) == (path == "kernel")
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=0,
+                                   atol=2e-6 * float(jnp.abs(w).max()))
+    np.testing.assert_array_equal(np.asarray(got[1][0]), np.asarray(pool[0]))
+
+
 def test_engine_serves_reference_argmax():
     """Prefill, then decode through KVSlotPool inside Engine.run: at every
     served position the token served is the reference's best (the gap

@@ -1,11 +1,13 @@
-"""Every registered tile of the five Pallas kernels compiles for a TPU v5e.
+"""Every registered tile of the six Pallas kernels compiles for a TPU v5e.
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached, so these tests need no chip.  Operands are at the
 widths of the models that use each kernel: flash_attention at
 qwen2.5-14b's layout (40 q heads over 8 kv heads of 128, S = T = 2048,
 bf16), wkv6 at rwkv6-3b's (40 heads of 64, T = 2048), wkv6_decode at
-rwkv6-3b's pool (32 layers, 128 rows, 40 heads of 64), rglru_scan at
+rwkv6-3b's pool (32 layers, 128 rows, 40 heads of 64), mamba2_decode at
+nemotron3-nano-30b-a3b's pool (23 blocks, 64 rows, 64 heads of 64 × 128,
+8 groups), rglru_scan at
 recurrentgemma-2b's width (2560) and rmsnorm at d = 5120.  The decode
 steps of rwkv6-3b (capacity 128) and of nemotron3-nano-30b-a3b (capacity
 64, 8 experts held, 1536 positions) are compiled whole, to check that
@@ -33,6 +35,8 @@ _FLASH = ((1, 2048, 8, 5, 128), (1, 2048, 8, 128), (1, 2048, 8, 128))
 _WKV6 = ((1, 2048, 40, 64),) * 4 + ((40, 64),)
 _WKV6_DECODE = ((32, 128, 40, 32, 128), ()) + ((128, 40, 64),) * 4 + (
     (40, 64),)
+_MAMBA2_DECODE = ((23, 64, 64, 64, 128), (), (64, 64), (64, 64, 64),
+                  (64, 8, 128), (64, 8, 128))
 _RGLRU = ((1, 2048, 2560),) * 2
 _RMSNORM = ((2048, 5120), (5120,))
 
@@ -41,6 +45,7 @@ _DTYPES = {
     "wkv6": (jnp.bfloat16,) * 3 + (jnp.float32, jnp.bfloat16),
     "wkv6_decode": (jnp.float32, jnp.int32) + (jnp.bfloat16,) * 3 + (
         jnp.float32, jnp.bfloat16),
+    "mamba2_decode": (jnp.float32, jnp.int32) + (jnp.float32,) * 4,
     "rglru_scan": (jnp.float32,) * 2,
     "rmsnorm": (jnp.bfloat16,) * 2,
 }
@@ -53,6 +58,8 @@ _CALLS = {
     "wkv6_decode": lambda kw: (
         lambda s, l, r, k, v, w, u: ops.wkv6_decode(s, l, r, k, v, w, u,
                                                     **kw)),
+    "mamba2_decode": lambda kw: (
+        lambda s, l, d, u, b, c: ops.mamba2_decode(s, l, d, u, b, c, **kw)),
     "rglru_scan": lambda kw: (lambda a, b: ops.rglru_scan(a, b, **kw)),
     "rmsnorm": lambda kw: (lambda x, w: ops.rmsnorm(x, w, **kw)),
 }
@@ -62,6 +69,7 @@ def _cases():
     cases = []
     for kernel, shapes in (("flash_attention", _FLASH), ("wkv6", _WKV6),
                            ("wkv6_decode", _WKV6_DECODE),
+                           ("mamba2_decode", _MAMBA2_DECODE),
                            ("rglru_scan", _RGLRU), ("rmsnorm", _RMSNORM)):
         for v in variants.variants_for(kernel, shapes):
             cases.append(pytest.param(kernel, shapes, v, id=v.label))
@@ -246,6 +254,29 @@ def _materialized_copies(hlo_text, shapes):
     return out
 
 
+def _readers(hlo_text, shape):
+    """(name, opcode, line) of each instruction of the entry computation
+    that takes a value of ``shape``, or a tuple holding one, as an
+    operand."""
+    held, out, entry = set(), [], False
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY"):
+            entry = True
+            continue
+        if entry and line.strip() == "}":
+            break
+        if not entry or " = " not in line:
+            continue
+        name, rhs = line.strip().split(" = ", 1)
+        name = name.replace("ROOT ", "").lstrip("%")
+        op = re.search(r"\s([a-z][\w\-]*)\(", rhs)
+        if set(re.findall(r"%([\w.\-]+)", rhs[op.end():])) & held:
+            out.append((name, op[1], line.strip()))
+        if shape + "{" in rhs[:op.start()]:
+            held.add(name)
+    return out
+
+
 def test_nemotron_h_decode_fits_and_updates_pool_in_place(one_chip,
                                                          no_compile_cache):
     """The decode step of nemotron3-nano-30b-a3b at its published widths,
@@ -253,7 +284,10 @@ def test_nemotron_h_decode_fits_and_updates_pool_in_place(one_chip,
     1536 positions, cache donated: the weights (8.08 GB) and the pool fit
     the chip's 16 GB, the program needs no second pool (temporaries under
     one block's SSM state), and no state- or KV-shaped copy is
-    materialized, of the whole pool or of one block's rows."""
+    materialized, of the whole pool or of one block's rows.  The SSM pool
+    is read only by the 23 ``mamba2_decode`` kernels, each under the
+    ``mamba2`` scope: no fusion slices a block's state out of it, so each
+    block's state is read once and written once."""
     cfg = dataclasses.replace(get_config("nemotron3-nano-30b-a3b"),
                               experts_held=8)
     m = Transformer(cfg)
@@ -286,3 +320,13 @@ def test_nemotron_h_decode_fits_and_updates_pool_in_place(one_chip,
     rows = ("f32[64,64,64,128]", "f32[1,64,64,64,128]",
             "bf16[64,1536,2,128]", "bf16[1,64,1536,2,128]")
     assert not _materialized_copies(text, rows)
+
+    readers = _readers(text, "f32[23,64,64,64,128]")
+    kernels = [ln for name, op, ln in readers if op == "custom-call"
+               and re.fullmatch(r"mamba2_decode(\.\d+)?", name)]
+    assert len(kernels) == 23
+    assert all(re.search(r'op_name="[^"]*/mamba2/', ln) for ln in kernels)
+    others = [ln[:160] for name, op, ln in readers
+              if op not in ("custom-call", "get-tuple-element", "tuple")]
+    assert not others, others
+    assert len(kernels) == sum(op == "custom-call" for _, op, _ in readers)
